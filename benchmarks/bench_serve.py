@@ -57,7 +57,7 @@ def parse_args(argv=None):
     ap.add_argument("--batch", type=int, default=4,
                     help="slots per bucket (must be a multiple of the data "
                          "axis width)")
-    ap.add_argument("--model-shard", type=int, default=2,
+    ap.add_argument("--model-shard", type=int, default=1,
                     help="model-axis width (Co-block sharding; 1 = pure "
                          "data parallelism)")
     ap.add_argument("--burst", type=int, default=6,
@@ -257,20 +257,20 @@ def merge_report(path: str, sections: dict, dispatch=None):
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    # the mesh needs its devices before jax initializes (same contract as
-    # the sharding tests): force the 8-device host platform first
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=8")
     import jax
     import numpy as np
     from repro.launch.conv_serve import ConvServer
-    from repro.launch.mesh import make_test_mesh
+    from repro.launch.mesh import make_serve_mesh
     from repro.nn.module import init_tree
+    from repro.utils.cache import enable_compile_cache
 
+    enable_compile_cache()
     model = build_smoke_model()
-    m = args.model_shard
-    data = max(1, jax.device_count() // max(m, 1))
-    mesh = make_test_mesh(data=data, model=max(m, 1))
+    m = max(args.model_shard, 1)
+    # the mesh covers the visible devices (virtual CPU devices come from
+    # XLA_FLAGS=--xla_force_host_platform_device_count, set by the caller)
+    mesh = make_serve_mesh(model=m)
+    data = mesh.shape["data"]
     batch = -(-args.batch // data) * data
     model_axis = "model" if m > 1 else None
 

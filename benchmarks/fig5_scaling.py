@@ -31,7 +31,6 @@ _SCRIPT = textwrap.dedent("""
     from repro.core import layout as L
     from repro.core.direct_conv import direct_conv_blocked
     from repro.utils.hlo import collective_bytes
-    from repro.utils.compat import cost_analysis_dict
 
     n = %(n)d
     from repro.launch.mesh import make_mesh_auto
@@ -53,25 +52,24 @@ _SCRIPT = textwrap.dedent("""
     comp = f.lower(xb, wb).compile()
     direct = {
         "collectives": collective_bytes(comp.as_text()),
-        "flops": float(cost_analysis_dict(comp).get("flops", 0.0)),
+        "flops": float(comp.cost_analysis().get("flops", 0.0)),
     }
 
     # --- direct conv, batch sharded via shard_map (the serving arrangement:
     #     repro.launch.conv_serve) — per-shard blocked layouts, and the
     #     forward pass must contain ZERO collectives
-    from repro.utils.compat import shard_map
     mesh_d = make_mesh_auto((n,), ("data",))
     xb_n = jax.ShapeDtypeStruct((n, s["ci"] // 128, s["hi"], s["wi"], 128),
                                 jnp.float32)
     wb_full = jax.ShapeDtypeStruct((s["co"] // 128, s["ci"] // 128, s["hf"],
                                     s["wf"], 128, 128), jnp.float32)
-    fb = jax.jit(shard_map(lambda x, w: direct_conv_blocked(x, w, 1),
-                           mesh_d, in_specs=(P("data"), P()),
-                           out_specs=P("data")))
+    fb = jax.jit(jax.shard_map(lambda x, w: direct_conv_blocked(x, w, 1),
+                               mesh=mesh_d, in_specs=(P("data"), P()),
+                               out_specs=P("data"), check_vma=False))
     comp_b = fb.lower(xb_n, wb_full).compile()
     batch_sharded = {
         "collectives": collective_bytes(comp_b.as_text()),
-        "flops": float(cost_analysis_dict(comp_b).get("flops", 0.0)),
+        "flops": float(comp_b.cost_analysis().get("flops", 0.0)),
     }
 
     # --- im2col+GEMM with the GEMM sharded over K (BLAS-internal style)
@@ -85,7 +83,7 @@ _SCRIPT = textwrap.dedent("""
     comp2 = g.lower(packed, wmat).compile()
     gemm = {
         "collectives": collective_bytes(comp2.as_text()),
-        "flops": float(cost_analysis_dict(comp2).get("flops", 0.0)),
+        "flops": float(comp2.cost_analysis().get("flops", 0.0)),
     }
     print(json.dumps({"n": n, "direct": direct,
                       "direct_batch_sharded": batch_sharded,
@@ -95,7 +93,10 @@ _SCRIPT = textwrap.dedent("""
 
 def bench_fig5(widths=(1, 2, 4, 8, 16)):
     rows = []
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # the children only compile, on virtual CPU devices: pin them to the
+    # CPU so they never reach for the chip the parent process holds
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     for n in widths:
         out = subprocess.run([sys.executable, "-c", _SCRIPT % {"n": n}],
                              capture_output=True, text=True, env=env,

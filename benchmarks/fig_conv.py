@@ -218,7 +218,7 @@ def bench_stream(shapes=None, iters=3, dtype_name="f32"):
     """The streamed halo-DMA kernel section (``--stream``, DESIGN.md §11).
 
     Per (shape, machine) pair: fwd and fwd+bwd step times through
-    ``direct_conv2d_blocked_pallas(stream=True)`` (interpret mode on CPU —
+    ``direct_conv2d_blocked_pallas(stream=True)`` (interpret mode off the TPU —
     the trajectory tracks relative drift, not TPU wall-clock), the window
     path's fwd time when its inequality fits (absent for the pathological
     rows: that path *raises* there, which is the point), and the
@@ -233,7 +233,7 @@ def bench_stream(shapes=None, iters=3, dtype_name="f32"):
         def stream_fn(xb_, wb_):
             return direct_conv2d_blocked_pallas(
                 xb_, wb_, stride=s.stride, padding=s.pad, machine=machine,
-                interpret=True, precision=dtype_name, stream=True)
+                precision=dtype_name, stream=True)
 
         t_fwd = time_fn(stream_fn, xb, wb, iters=iters, dtype=dtype)
         t_step = time_fn(stream_fn, xb, wb, iters=iters, backward=True,
@@ -253,8 +253,7 @@ def bench_stream(shapes=None, iters=3, dtype_name="f32"):
             def window_fn(xb_, wb_):
                 return direct_conv2d_blocked_pallas(
                     xb_, wb_, stride=s.stride, padding=s.pad,
-                    machine=machine, interpret=True, precision=dtype_name,
-                    stream=False)
+                    machine=machine, precision=dtype_name, stream=False)
             row["window_fwd_us"] = time_fn(window_fn, xb, wb, iters=iters,
                                            dtype=dtype) * 1e6
         except VmemMisfitError:
@@ -289,7 +288,7 @@ def bench_fusion(shapes=None, iters=3, dtype_name="f32"):
                              lay.cb_out)), jnp.float32)
 
         kw = dict(stride=s.stride, padding=s.pad, activation="relu",
-                  interpret=True, precision=dtype_name)
+                  precision=dtype_name)
 
         if gap:
             def fused_fn(xb_, wb_):

@@ -27,6 +27,8 @@ def main() -> None:
     ap.add_argument("--skip-fig5", action="store_true")
     ap.add_argument("--artifacts", default="artifacts/dryrun")
     args = ap.parse_args()
+    from repro.utils.cache import enable_compile_cache
+    enable_compile_cache()
 
     from .cnn_zoo import ALEXNET, ZOO
     from .fig_conv import bench_fig1_packing_split, bench_fig4

@@ -11,9 +11,11 @@ measurement vector) across {f32, bf16} x {fwd, dgrad, wgrad}.  The
 with ``source: "prior"`` and ``check_regression --dispatch-table`` reports
 them as "untuned" without gating.
 
-Off-TPU the Pallas candidates time in interpret mode, so the table encodes
-the *relative kernel trajectory*, not TPU wall-clock — the same contract as
-``BENCH_baseline.json`` (both regenerate together when shapes change).
+A time recorded under a TPU machine name is a chip time: off the TPU the
+Pallas candidates run in interpret mode, so ``tune_key`` refuses to time a
+TPU-named key there, and ``regenerate`` prior-seeds those keys instead
+("not measured").  Keys of the CPU test machines (the deep-pencil model)
+are timed anywhere.
 
 Runnable (the ``-m`` form is required — relative imports):
 
@@ -32,6 +34,9 @@ from __future__ import annotations
 import argparse
 import sys
 
+import jax
+
+from repro.core.backend import DEVICE_KINDS
 from repro.core.blocking import TPU_V5E
 from repro.core.dispatch import (DIRECTIONS, ConvDispatcher, DispatchKey,
                                  default_table_path)
@@ -75,11 +80,39 @@ def prior_keys():
     return keys
 
 
+# the machine names a time may only be recorded under on the chip
+TPU_MACHINES = frozenset(m.name for m in DEVICE_KINDS.values())
+
+
+def measurable(key: DispatchKey) -> bool:
+    """Whether this process may record times for ``key``: a TPU-named key
+    only on a TPU backend."""
+    return key.machine not in TPU_MACHINES or jax.default_backend() == "tpu"
+
+
+def tune_key(disp: ConvDispatcher, key: DispatchKey, iters: int = 3):
+    """``disp.tune(key)``, refusing to write interpret-mode times under a
+    TPU machine name."""
+    if not measurable(key):
+        raise ValueError(
+            f"refusing to time {key.ident} on the {jax.default_backend()} "
+            f"backend: {key.machine!r} names a TPU, and a time recorded "
+            "under it must come from the chip")
+    return disp.tune(key, iters=iters)
+
+
 def regenerate(iters: int = 3, verbose: bool = True) -> ConvDispatcher:
-    """Tune + prior-seed a fresh table in memory (nothing written)."""
+    """Tune + prior-seed a fresh table in memory (nothing written).  Keys
+    this backend may not time (``measurable``) are prior-seeded."""
     disp = ConvDispatcher(path=default_table_path())
     for key in tuned_keys():
-        dec = disp.tune(key, iters=iters)
+        if not measurable(key):
+            dec = disp.seed_prior(key)
+            if verbose:
+                print(f"prior  {key.ident}: {dec.impl.value}  "
+                      "(not measured: needs the chip)")
+            continue
+        dec = tune_key(disp, key, iters=iters)
         if verbose:
             times = " ".join(f"{k}={v:.0f}us"
                              for k, v in sorted(dec.times_us.items()))

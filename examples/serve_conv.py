@@ -35,7 +35,9 @@ def main():
     from repro.nn.conv import BlockedCNN, BlockedConv2D
     from repro.nn.module import init_tree
     from repro.serve import ConvRequest, ConvServer
+    from repro.utils.cache import enable_compile_cache
 
+    enable_compile_cache()
     model = BlockedCNN(convs=(
         BlockedConv2D(ci=8, co=16, lane=8),
         BlockedConv2D(ci=16, co=32, stride=2, lane=8),

@@ -50,6 +50,7 @@ from repro.core.dispatch import ConvDispatcher
 from repro.nn.conv import BlockedCNN, BlockedConv2D, DepthwiseSeparableBlock
 from repro.nn.module import init_tree
 from repro.train.optimizer import AdamW, cosine_schedule
+from repro.utils.cache import enable_compile_cache
 
 CB = 8   # channel pencil for this toy net (lane=128 on real TPU)
 
@@ -135,6 +136,7 @@ def main():
                     help="mixed-precision policy: bf16 operands/residuals "
                          "with f32 accumulators + master params")
     args = ap.parse_args()
+    enable_compile_cache()
 
     model = MODELS[args.model]
     p = init_tree(model.specs(), jax.random.PRNGKey(0))

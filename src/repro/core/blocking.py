@@ -30,7 +30,7 @@ from .layout import choose_pencil, divisors, largest_divisor_leq
 from .precision import resolve_precision
 
 __all__ = [
-    "MachineModel", "TPU_V5E", "CPU_HASWELL", "Blocking", "StreamBlocking",
+    "MachineModel", "TPU_V5E", "CPU_HASWELL", "tile_bytes", "Blocking", "StreamBlocking",
     "VmemMisfitError",
     "cpu_min_tile_elems", "cpu_max_tile_elems", "resident_bytes",
     "choose_blocking", "dgrad_extents", "choose_dgrad_blocking",
@@ -84,18 +84,70 @@ class MachineModel:
     peak_flops: float = 0.0      # per-chip peak (bf16 for TPU)
     hbm_bw: float = 0.0          # bytes/s
     ici_bw: float = 0.0          # bytes/s per link
+    vmem_limit_bytes: int = 0    # scoped-VMEM limit every launch sets
+    tile: tuple | None = None    # VMEM memory tile (rows of 32-bit words,
+                                 # lanes) blocks pad to; None = unpadded
+    max_tile_rows: int = 0       # cap on a tile's matmul rows (hob*wob);
+                                 # 0 = uncapped
+    source: str = ""             # where the peaks above come from
 
 
-# TPU v5e — the roofline constants used across benchmarks/ and EXPERIMENTS.md.
+# TPU v5e.  Peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over four links).  VMEM: the chip
+# has 128 MiB; launches ask the compiler for ``vmem_limit_bytes`` of it and
+# the blocking models fit their padded, double-buffered tiles into the
+# smaller ``vmem_bytes`` — the rest is Mosaic's own room for the values a
+# kernel body holds (tap windows, matmul results, the widened source of a
+# strided bf16 launch).  Both numbers are set from what compiles for the
+# chip (tests/test_tpu_compile.py, DESIGN.md §7).
 TPU_V5E = MachineModel(
     name="tpu_v5e", n_vec=128, n_fma=1, l_fma=8, n_reg=512,
-    vmem_bytes=64 * 2**20, mxu=128,
+    vmem_bytes=48 * 2**20, mxu=128,
     peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+    vmem_limit_bytes=100 * 2**20, tile=(8, 128), max_tile_rows=2048,
+    source='Google Cloud documentation, "TPU v5e"',
 )
 
 # Paper Table 1, Intel i7-4770K (Haswell): AVX2 (8 f32 lanes), 2 FMA units,
 # latency 5, 16 logical ymm registers.
 CPU_HASWELL = MachineModel(name="haswell", n_vec=8, n_fma=2, l_fma=5, n_reg=16)
+
+
+def _ceil(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def tile_bytes(shape, itemsize: int, tile=None) -> int:
+    """VMEM bytes of one block.  ``tile = (rows, lanes)`` is the machine's
+    memory tile (:attr:`MachineModel.tile`; v5e: 8 rows of 32-bit words by
+    128 lanes): the minor dim pads to ``lanes``, the second-minor to
+    ``rows`` words (8 rows of f32, 16 of bf16), leading dims stay as they
+    are — so a narrow pencil costs what it really occupies.  ``tile=None``
+    is the unpadded byte count (the paper's model; tiny test machines)."""
+    shape = tuple(shape)
+    n = 1
+    for d in shape:
+        n *= d
+    if tile is None:
+        return n * itemsize
+    if len(shape) == 1:
+        shape = (1,) + shape
+    *lead, rows, lanes = shape
+    n = 1
+    for d in lead:
+        n *= d
+    return (n * _ceil(rows, tile[0] * max(1, 4 // itemsize))
+            * _ceil(lanes, tile[1]) * itemsize)
+
+
+def _widened(hib: int, wib: int, cb: int, stride: int,
+             in_dtype_bytes: int, tile) -> int:
+    """The scoped f32 copy a strided launch over sub-32-bit operands makes
+    of its window on the chip (``kernels.conv2d_common.strided_source``);
+    only a tiled (chip) model counts it."""
+    if tile is None or stride == 1 or in_dtype_bytes >= 4:
+        return 0
+    return tile_bytes((hib, wib, cb), 4, tile)
 
 
 def cpu_min_tile_elems(m: MachineModel) -> int:
@@ -125,7 +177,7 @@ def resident_bytes(hob: int, wob: int, cob: int, cib: int, hf: int, wf: int,
                    stride: int = 1, in_dtype_bytes: int = 4,
                    acc_dtype_bytes: int = 4, dilation=(1, 1),
                    fused_residual: bool = False, fused_gap: bool = False,
-                   fused_prologue: bool = False) -> int:
+                   fused_prologue: bool = False, tile=None) -> int:
     """VMEM bytes one Pallas grid step holds resident (DESIGN.md §7):
     double-buffered halo'd input window, weight tile and output tile
     (Pallas pipelines all operand blocks), plus the persistent f32
@@ -145,18 +197,28 @@ def resident_bytes(hob: int, wob: int, cob: int, cib: int, hf: int, wf: int,
     dh, dw = as_dilation(dilation)
     hib = (hob - 1) * stride + (hf - 1) * dh + 1          # halo'd input rows
     wib = (wob - 1) * stride + (wf - 1) * dw + 1          # halo'd input cols
-    win = hib * wib * cib * in_dtype_bytes
-    wgt = hf * wf * cib * cob * in_dtype_bytes
-    out = hob * wob * cob * in_dtype_bytes                # output block
-    acc = hob * wob * cob * acc_dtype_bytes               # scratch (single)
-    total = 2 * (win + wgt + out) + acc
+    win = tile_bytes((hib, wib, cib), in_dtype_bytes, tile)
+    wgt = tile_bytes((hf, wf, cib, cob), in_dtype_bytes, tile)
+    out = tile_bytes((hob, wob, cob), in_dtype_bytes, tile)     # output block
+    acc = tile_bytes((hob * wob, cob), acc_dtype_bytes, tile)   # scratch (single)
+    total = (2 * (win + wgt + out) + acc
+             + _widened(hib, wib, cib, stride, in_dtype_bytes, tile))
     if fused_residual:
         total += 2 * out                                  # skip-branch tile
     if fused_gap:
-        total += 2 * cob * in_dtype_bytes + cob * acc_dtype_bytes
+        total += (2 * tile_bytes((1, cob), in_dtype_bytes, tile)
+                  + tile_bytes((1, cob), acc_dtype_bytes, tile))
     if fused_prologue:
         total += 2 * win                                  # z rides with g
     return total
+
+
+def _rows_ok(machine: MachineModel, rows: int) -> bool:
+    """The tile-size ceiling next to Eq. 1's floor: Mosaic unrolls a kernel
+    body over the tile's vregs, so its compile time grows with the tile
+    while the per-step overhead it amortizes stops mattering well before a
+    whole 112x112 map (``MachineModel.max_tile_rows``)."""
+    return not machine.max_tile_rows or rows <= machine.max_tile_rows
 
 
 def _shrink_to_fit(extent: int, cur: int, pinned: bool, fits) -> int:
@@ -278,7 +340,9 @@ def choose_blocking(
                                   fused_residual=fused_residual,
                                   fused_gap=fused_gap,
                                   fused_prologue=fused_prologue,
-                                  ) <= machine.vmem_bytes
+                                  tile=machine.tile,
+                                  ) <= machine.vmem_bytes and _rows_ok(
+                                      machine, hob_ * wob_)
 
         hob = _shrink_to_fit(ho, hob, hob_pinned,
                              lambda h: fits(cib, h, wob))
@@ -385,7 +449,7 @@ def wgrad_resident_bytes(hob: int, wob: int, cob: int, cib: int,
                          in_dtype_bytes: int = 4,
                          acc_dtype_bytes: int = 4, dilation=(1, 1),
                          fused_prologue: bool = False,
-                         fused_bias: bool = False) -> int:
+                         fused_bias: bool = False, tile=None) -> int:
     """VMEM bytes one wgrad grid step holds resident (DESIGN.md §9).
 
     Same double-buffered operand accounting as :func:`resident_bytes`, but
@@ -401,15 +465,16 @@ def wgrad_resident_bytes(hob: int, wob: int, cob: int, cib: int,
     dh, dw = as_dilation(dilation)
     hib = (hob - 1) * stride + (hf - 1) * dh + 1
     wib = (wob - 1) * stride + (wf - 1) * dw + 1
-    win = hib * wib * cib * in_dtype_bytes                # x window (halo'd)
-    cot = hob * wob * cob * in_dtype_bytes                # cotangent tile
-    wgt = hf * wf * cib * cob * in_dtype_bytes            # dw output block
-    acc = hf * wf * cib * cob * acc_dtype_bytes           # scratch (single)
-    total = 2 * (win + cot + wgt) + acc
+    win = tile_bytes((hib, wib, cib), in_dtype_bytes, tile)     # x window (halo'd)
+    cot = tile_bytes((hob, wob, cob), in_dtype_bytes, tile)     # cotangent tile
+    wgt = tile_bytes((hf, wf, cib, cob), in_dtype_bytes, tile)  # dw output block
+    acc = tile_bytes((hf, wf, cib, cob), acc_dtype_bytes, tile)  # scratch (single)
+    total = (2 * (win + cot + wgt) + acc
+             + _widened(hib, wib, cib, stride, in_dtype_bytes, tile))
     if fused_prologue:
         total += 2 * cot                                  # z rides with g
     if fused_bias:
-        total += 2 * cob * acc_dtype_bytes + cob * acc_dtype_bytes
+        total += 3 * tile_bytes((1, cob), acc_dtype_bytes, tile)
     return total
 
 
@@ -456,7 +521,9 @@ def choose_wgrad_blocking(
                 hob_, wob_, cob, cib, hf, wf, stride,
                 in_dtype_bytes, acc_dtype_bytes,
                 dilation=dilation, fused_prologue=fused_prologue,
-                fused_bias=fused_bias) <= machine.vmem_bytes
+                fused_bias=fused_bias,
+                tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
+                    machine, hob_ * wob_)
 
         hob = _shrink_to_fit(ho, hob, hob_pinned, lambda h: fits(h, wob))
         wob = _shrink_to_fit(wo, wob, wob_pinned, lambda w: fits(hob, w))
@@ -507,7 +574,7 @@ def stream_resident_bytes(hso: int, hob: int, wob: int, cob: int, cib: int,
                           in_dtype_bytes: int = 4,
                           acc_dtype_bytes: int = 4,
                           fused_residual: bool = False,
-                          fused_gap: bool = False) -> int:
+                          fused_gap: bool = False, tile=None) -> int:
     """VMEM bytes one streamed fwd/dgrad grid step holds resident:
 
         weights   hf*wf*cib*cob       x1  (manual DMA into scratch — the
@@ -527,15 +594,17 @@ def stream_resident_bytes(hso: int, hob: int, wob: int, cob: int, cib: int,
     pooled pencil output plus its f32 partial-sum scratch (DESIGN.md §14)."""
     hin = (hso - 1) * stride + hf
     wib = (wob - 1) * stride + wf
-    wgt = hf * wf * cib * cob * in_dtype_bytes
-    ring = 2 * hin * wib * cib * in_dtype_bytes
-    out = 2 * hob * wob * cob * in_dtype_bytes
-    acc = hob * wob * cob * acc_dtype_bytes
-    total = wgt + ring + out + acc
+    wgt = tile_bytes((hf, wf, cib, cob), in_dtype_bytes, tile)
+    ring = tile_bytes((2, hin, wib, cib), in_dtype_bytes, tile)
+    out = 2 * tile_bytes((hob, wob, cob), in_dtype_bytes, tile)
+    acc = tile_bytes((hob * wob, cob), acc_dtype_bytes, tile)
+    total = (wgt + ring + out + acc
+             + _widened(hin, wib, cib, stride, in_dtype_bytes, tile))
     if fused_residual:
-        total += 2 * hob * wob * cob * in_dtype_bytes
+        total += out
     if fused_gap:
-        total += 2 * cob * in_dtype_bytes + cob * acc_dtype_bytes
+        total += (2 * tile_bytes((1, cob), in_dtype_bytes, tile)
+                  + tile_bytes((1, cob), acc_dtype_bytes, tile))
     return total
 
 
@@ -603,7 +672,9 @@ def choose_stream_blocking(
                 hso_, hob_, wob_, cob, cib, hf, wf, stride,
                 in_dtype_bytes, acc_dtype_bytes,
                 fused_residual=fused_residual,
-                fused_gap=fused_gap) <= machine.vmem_bytes
+                fused_gap=fused_gap,
+                tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
+                    machine, hob_ * wob_)
 
         hso = _shrink_to_fit(hob, hso, hso_pinned,
                              lambda s: fits(s, hob, wob))
@@ -658,7 +729,7 @@ def choose_stream_dgrad_blocking(
 def stream_wgrad_resident_bytes(hso: int, wob: int, cob: int, cib: int,
                                 hf: int, wf: int, stride: int = 1,
                                 in_dtype_bytes: int = 4,
-                                acc_dtype_bytes: int = 4) -> int:
+                                acc_dtype_bytes: int = 4, tile=None) -> int:
     """VMEM bytes one streamed wgrad grid step holds resident.
 
     Both operands stream (a halo'd x ring and a disjoint cotangent ring);
@@ -670,9 +741,10 @@ def stream_wgrad_resident_bytes(hso: int, wob: int, cob: int, cib: int,
     """
     hin = (hso - 1) * stride + hf
     wib = (wob - 1) * stride + wf
-    rings = 2 * (hin * wib * cib + hso * wob * cob) * in_dtype_bytes
-    acc = hf * wf * cib * cob * acc_dtype_bytes
-    return rings + acc
+    rings = (tile_bytes((2, hin, wib, cib), in_dtype_bytes, tile)
+             + tile_bytes((2, hso, wob, cob), in_dtype_bytes, tile))
+    acc = tile_bytes((hf, wf, cib, cob), acc_dtype_bytes, tile)
+    return rings + acc + _widened(hin, wib, cib, stride, in_dtype_bytes, tile)
 
 
 def choose_stream_wgrad_blocking(
@@ -711,7 +783,9 @@ def choose_stream_wgrad_blocking(
         def fits(hso_, wob_):
             return stream_wgrad_resident_bytes(
                 hso_, wob_, cob, cib, hf, wf, stride,
-                in_dtype_bytes, acc_dtype_bytes) <= machine.vmem_bytes
+                in_dtype_bytes, acc_dtype_bytes,
+                tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
+                    machine, hso_ * wob_)
 
         hso = _shrink_to_fit(ho, hso, hso_pinned, lambda s: fits(s, wob))
         wob = _shrink_to_fit(wo, wob, wob_pinned, lambda w: fits(hso, w))
@@ -738,7 +812,7 @@ def depthwise_resident_bytes(hob: int, wob: int, cb: int, hf: int, wf: int,
                              dilation=(1, 1),
                              fused_residual: bool = False,
                              fused_gap: bool = False,
-                             fused_prologue: bool = False) -> int:
+                             fused_prologue: bool = False, tile=None) -> int:
     """VMEM bytes one depthwise grid step holds resident: double-buffered
     halo'd window, [Hf, Wf, Cb] tap stack and output tile, plus the f32
     accumulator.  The fused riders (residual tile / GAP pencil + scratch /
@@ -746,15 +820,17 @@ def depthwise_resident_bytes(hob: int, wob: int, cb: int, hf: int, wf: int,
     dh, dw = as_dilation(dilation)
     hib = (hob - 1) * stride + (hf - 1) * dh + 1
     wib = (wob - 1) * stride + (wf - 1) * dw + 1
-    win = hib * wib * cb * in_dtype_bytes
-    wgt = hf * wf * cb * in_dtype_bytes
-    out = hob * wob * cb * in_dtype_bytes
-    acc = hob * wob * cb * acc_dtype_bytes
-    total = 2 * (win + wgt + out) + acc
+    win = tile_bytes((hib, wib, cb), in_dtype_bytes, tile)
+    wgt = tile_bytes((hf, wf, 1, cb), in_dtype_bytes, tile)
+    out = tile_bytes((hob, wob, cb), in_dtype_bytes, tile)
+    acc = tile_bytes((hob * wob, cb), acc_dtype_bytes, tile)
+    total = (2 * (win + wgt + out) + acc
+             + _widened(hib, wib, cb, stride, in_dtype_bytes, tile))
     if fused_residual:
         total += 2 * out
     if fused_gap:
-        total += 2 * cb * in_dtype_bytes + cb * acc_dtype_bytes
+        total += (2 * tile_bytes((1, cb), in_dtype_bytes, tile)
+                  + tile_bytes((1, cb), acc_dtype_bytes, tile))
     if fused_prologue:
         total += 2 * win
     return total
@@ -799,7 +875,9 @@ def choose_depthwise_blocking(
                 hob_, wob_, cb, hf, wf, stride, in_dtype_bytes,
                 acc_dtype_bytes, dilation=dil,
                 fused_residual=fused_residual, fused_gap=fused_gap,
-                fused_prologue=fused_prologue) <= machine.vmem_bytes
+                fused_prologue=fused_prologue,
+                tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
+                    machine, hob_ * wob_)
 
         hob = _shrink_to_fit(ho, hob, hob_pinned, lambda h: fits(h, wob))
         wob = _shrink_to_fit(wo, wob, wob_pinned, lambda w: fits(hob, w))
@@ -817,7 +895,7 @@ def depthwise_wgrad_resident_bytes(hob: int, wob: int, cb: int,
                                    acc_dtype_bytes: int = 4,
                                    dilation=(1, 1),
                                    fused_prologue: bool = False,
-                                   fused_bias: bool = False) -> int:
+                                   fused_bias: bool = False, tile=None) -> int:
     """Depthwise wgrad residency: halo'd x window, cotangent tile, and the
     per-channel [Hf*Wf, Cb] tap-gradient accumulator.  With ``fused_prologue``
     the saved pre-activation ``z`` tile rides next to the cotangent; with
@@ -826,15 +904,16 @@ def depthwise_wgrad_resident_bytes(hob: int, wob: int, cb: int,
     dh, dw = as_dilation(dilation)
     hib = (hob - 1) * stride + (hf - 1) * dh + 1
     wib = (wob - 1) * stride + (wf - 1) * dw + 1
-    win = hib * wib * cb * in_dtype_bytes
-    cot = hob * wob * cb * in_dtype_bytes
-    wgt = hf * wf * cb * in_dtype_bytes
-    acc = hf * wf * cb * acc_dtype_bytes
-    total = 2 * (win + cot + wgt) + acc
+    win = tile_bytes((hib, wib, cb), in_dtype_bytes, tile)
+    cot = tile_bytes((hob, wob, cb), in_dtype_bytes, tile)
+    wgt = tile_bytes((hf, wf, 1, cb), in_dtype_bytes, tile)
+    acc = tile_bytes((hf * wf, cb), acc_dtype_bytes, tile)
+    total = (2 * (win + cot + wgt) + acc
+             + _widened(hib, wib, cb, stride, in_dtype_bytes, tile))
     if fused_prologue:
         total += 2 * cot
     if fused_bias:
-        total += 3 * cb * acc_dtype_bytes
+        total += 3 * tile_bytes((1, cb), acc_dtype_bytes, tile)
     return total
 
 
@@ -869,7 +948,9 @@ def choose_depthwise_wgrad_blocking(
                 hob_, wob_, cb, hf, wf, stride, in_dtype_bytes,
                 acc_dtype_bytes, dilation=dilation,
                 fused_prologue=fused_prologue,
-                fused_bias=fused_bias) <= machine.vmem_bytes
+                fused_bias=fused_bias,
+                tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
+                    machine, hob_ * wob_)
 
         hob = _shrink_to_fit(ho, hob, hob_pinned, lambda h: fits(h, wob))
         wob = _shrink_to_fit(wo, wob, wob_pinned, lambda w: fits(hob, w))
@@ -892,21 +973,22 @@ def pointwise_resident_bytes(hob: int, wob: int, cob: int, cib: int,
                              acc_dtype_bytes: int = 4,
                              fused_residual: bool = False,
                              fused_gap: bool = False,
-                             fused_prologue: bool = False) -> int:
+                             fused_prologue: bool = False, tile=None) -> int:
     """VMEM bytes one pointwise grid step holds resident: double-buffered
     input tile, [Cib, Cob] weight matrix and output tile, plus the f32
     accumulator.  Fused riders follow :func:`resident_bytes`; for the dgrad
     flavor ``fused_prologue`` adds the ``z`` tile pipelined next to the
     incoming cotangent."""
-    xin = hob * wob * cib * in_dtype_bytes
-    wgt = cib * cob * in_dtype_bytes
-    out = hob * wob * cob * in_dtype_bytes
-    acc = hob * wob * cob * acc_dtype_bytes
+    xin = tile_bytes((hob, wob, cib), in_dtype_bytes, tile)
+    wgt = tile_bytes((cib, cob), in_dtype_bytes, tile)
+    out = tile_bytes((hob, wob, cob), in_dtype_bytes, tile)
+    acc = tile_bytes((hob * wob, cob), acc_dtype_bytes, tile)
     total = 2 * (xin + wgt + out) + acc
     if fused_residual:
         total += 2 * out
     if fused_gap:
-        total += 2 * cob * in_dtype_bytes + cob * acc_dtype_bytes
+        total += (2 * tile_bytes((1, cob), in_dtype_bytes, tile)
+                  + tile_bytes((1, cob), acc_dtype_bytes, tile))
     if fused_prologue:
         total += 2 * xin
     return total
@@ -951,7 +1033,9 @@ def choose_pointwise_blocking(
                 hob_, wob_, cob, cib_, in_dtype_bytes,
                 acc_dtype_bytes, fused_residual=fused_residual,
                 fused_gap=fused_gap,
-                fused_prologue=fused_prologue) <= machine.vmem_bytes
+                fused_prologue=fused_prologue,
+                tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
+                    machine, hob_ * wob_)
 
         hob = _shrink_to_fit(ho, hob, hob_pinned, lambda h: fits(cib, h, wob))
         wob = _shrink_to_fit(wo, wob, wob_pinned, lambda w: fits(cib, hob, w))
@@ -968,20 +1052,20 @@ def pointwise_wgrad_resident_bytes(hob: int, wob: int, cob: int, cib: int,
                                    in_dtype_bytes: int = 4,
                                    acc_dtype_bytes: int = 4,
                                    fused_prologue: bool = False,
-                                   fused_bias: bool = False) -> int:
+                                   fused_bias: bool = False, tile=None) -> int:
     """Pointwise wgrad residency: x tile, cotangent tile, and the [Cib, Cob]
     weight-gradient block + matching f32 accumulator.  ``fused_prologue``
     adds the saved ``z`` tile, ``fused_bias`` the [1, Cob] db block plus
     its f32 scratch."""
-    xin = hob * wob * cib * in_dtype_bytes
-    cot = hob * wob * cob * in_dtype_bytes
-    wgt = cib * cob * in_dtype_bytes
-    acc = cib * cob * acc_dtype_bytes
+    xin = tile_bytes((hob, wob, cib), in_dtype_bytes, tile)
+    cot = tile_bytes((hob, wob, cob), in_dtype_bytes, tile)
+    wgt = tile_bytes((cib, cob), in_dtype_bytes, tile)
+    acc = tile_bytes((cib, cob), acc_dtype_bytes, tile)
     total = 2 * (xin + cot + wgt) + acc
     if fused_prologue:
         total += 2 * cot
     if fused_bias:
-        total += 3 * cob * acc_dtype_bytes
+        total += 3 * tile_bytes((1, cob), acc_dtype_bytes, tile)
     return total
 
 
@@ -1015,7 +1099,9 @@ def choose_pointwise_wgrad_blocking(
             return pointwise_wgrad_resident_bytes(
                 hob_, wob_, cob, cib, in_dtype_bytes,
                 acc_dtype_bytes, fused_prologue=fused_prologue,
-                fused_bias=fused_bias) <= machine.vmem_bytes
+                fused_bias=fused_bias,
+                tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
+                    machine, hob_ * wob_)
 
         hob = _shrink_to_fit(ho, hob, hob_pinned, lambda h: fits(h, wob))
         wob = _shrink_to_fit(wo, wob, wob_pinned, lambda w: fits(hob, w))

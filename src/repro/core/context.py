@@ -31,6 +31,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Union
 
+from .backend import resolve_machine
 from .blocking import MachineModel
 from .dispatch import ConvDispatcher, Impl, KernelRoute
 from .precision import Precision, resolve_precision
@@ -61,7 +62,8 @@ class ConvContext:
       interpret  run Pallas kernels in interpret mode (None -> auto:
                  interpret off-TPU).
       machine    :class:`MachineModel` the blocking models fit against
-                 (None -> the layer's ``machine`` field).
+                 (None -> the layer's ``machine`` field, else the running
+                 device's model).
       stream     window-vs-stream override inside the dense Pallas family:
                  bool forces all three directions, a :class:`KernelRoute`
                  pins them per direction, None lets the dispatcher resolve.
@@ -98,9 +100,12 @@ class ConvContext:
         return resolve_precision(
             layer_default if self.precision is None else self.precision)
 
-    def resolve_machine_for(self, layer_default: MachineModel
+    def resolve_machine_for(self, layer_default: Optional[MachineModel]
                             ) -> MachineModel:
-        return layer_default if self.machine is None else self.machine
+        """This context's model, else the layer's, else the running
+        device's (``core.backend.machine_for_device``)."""
+        return resolve_machine(
+            layer_default if self.machine is None else self.machine)
 
     def resolve_stream_for(self, layer_default) -> Stream:
         return layer_default if self.stream is None else self.stream

@@ -72,6 +72,7 @@ import pathlib
 import warnings
 from typing import Callable, Dict, Iterable, Optional, Tuple, Union
 
+from .backend import resolve_interpret, resolve_machine
 from .blocking import (MachineModel, TPU_V5E, CPU_HASWELL, VmemMisfitError,
                        choose_blocking, choose_depthwise_blocking,
                        choose_depthwise_wgrad_blocking, choose_dgrad_blocking,
@@ -256,7 +257,7 @@ class DispatchKey:
     @classmethod
     def make(cls, n: int, hi: int, wi: int, ci: int, co: int, hf: int,
              wf: int, stride: int = 1, padding: Padding = "VALID",
-             precision=None, machine: MachineModel = TPU_V5E,
+             precision=None, machine: Optional[MachineModel] = None,
              direction: Direction = "fwd", *, groups: int = 1,
              dilation=1, fusion: str = "") -> "DispatchKey":
         """Build a key from call-site vocabulary (padding normalized by
@@ -264,8 +265,8 @@ class DispatchKey:
         canonical identity — SAME resolves against the *dilated* filter
         extent).  The machine model is registered as a side effect, so
         custom models (tests, pathological budgets) resolve by name in the
-        probes."""
-        register_machine(machine)
+        probes.  ``machine`` defaults to the running device's model."""
+        machine = register_machine(resolve_machine(machine))
         spec = ConvSpec.make(n, hi, wi, ci, co, hf, wf, stride=stride,
                              padding=padding, groups=groups,
                              dilation=dilation)
@@ -273,7 +274,8 @@ class DispatchKey:
                    machine=machine.name, direction=direction, fusion=fusion)
 
     @classmethod
-    def from_shape(cls, s, precision=None, machine: MachineModel = TPU_V5E,
+    def from_shape(cls, s, precision=None,
+                   machine: Optional[MachineModel] = None,
                    direction: Direction = "fwd",
                    fusion: str = "") -> "DispatchKey":
         """From a ``memory_model.ConvShape`` (the benchmark vocabulary)."""
@@ -695,8 +697,7 @@ def _pallas_costly() -> bool:
     """True when a Pallas launch would run in interpret mode (non-TPU
     backend): the prior then prefers the XLA-scheduled oracle, preserving
     the pre-dispatcher default for untouched call sites."""
-    import jax
-    return jax.default_backend() != "tpu"
+    return resolve_interpret(None)
 
 
 def prior_order(key: DispatchKey,
@@ -905,6 +906,12 @@ class ConvDispatcher:
             return Decision(impl=override, source="override", key=key)
 
         entry = self.lookup(key)
+        if entry is not None and entry.get("source") == "prior":
+            # a prior-seeded entry records coverage, not evidence: the prior
+            # is a function of the running backend (``prior_order``), so it
+            # is re-derived here rather than replayed from the machine that
+            # seeded the file
+            entry = None
         if entry is not None:
             impl = Impl(entry["impl"])
             source = "tuned" if key.ident in self._tuned else "table"
@@ -1011,8 +1018,7 @@ class ConvDispatcher:
         (source "tuned"); ``persist=True`` saves the file too.
         """
         timer = timer or _default_timer()
-        if interpret is None:
-            interpret = _pallas_costly()
+        interpret = resolve_interpret(interpret)
         ops = _tune_operands(key)
         times: Dict[str, float] = {}
         for impl in candidates_for(key):
@@ -1084,7 +1090,7 @@ def _blocked_groups(xb, wb) -> int:
 
 def run_conv_impl(impl: Impl, xb, wb, bias=None, *, stride: int = 1,
                   padding: Padding = "VALID", activation=None,
-                  precision=None, machine: MachineModel = TPU_V5E,
+                  precision=None, machine: Optional[MachineModel] = None,
                   interpret: Optional[bool] = None,
                   hob: Optional[int] = None, wob: Optional[int] = None,
                   hso: Optional[int] = None, route=None, dilation=1,
@@ -1115,8 +1121,6 @@ def run_conv_impl(impl: Impl, xb, wb, bias=None, *, stride: int = 1,
     pol = resolve_precision(precision)
     groups = _blocked_groups(xb, wb)
     dilation = as_dilation(dilation)
-    if interpret is None and impl in PALLAS_FAMILY:
-        interpret = _pallas_costly()
 
     if impl in PALLAS_IMPLS or impl is Impl.GROUPED:
         from repro.kernels.direct_conv2d import direct_conv2d_blocked_pallas

@@ -21,6 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.backend import resolve_machine
+from .conv2d_common import compiler_params
+
 __all__ = ["conv1d_depthwise_blocked_pallas"]
 
 
@@ -65,5 +68,7 @@ def conv1d_depthwise_blocked_pallas(x: jnp.ndarray, w: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, 1, lb, db), lambda b_, d, li: (b_, d, li, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=compiler_params(resolve_machine(None),
+                                        ("parallel",) * 3),
         interpret=interpret,
     )(x, x, w)

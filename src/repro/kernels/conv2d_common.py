@@ -8,8 +8,8 @@ that a kernel is only its contraction body:
 
 * ``halo_dims`` / ``halo_window_spec`` — the overlapping (halo'd) input
   window that plain Blocked indexing cannot express.  Adjacent tiles overlap
-  by the ``Hf - stride`` / ``Wf - stride`` halos, so the BlockSpec uses
-  element-offset indexing (``pl.Unblocked``): the index map returns
+  by the ``Hf - stride`` / ``Wf - stride`` halos, so the BlockSpec indexes
+  by element (every block dim a ``pl.Element``): the index map returns
   ``tile * tile_extent * stride`` directly.  Safe with no out-of-bounds
   semantics because every tile extent divides the corresponding output
   extent (``core.blocking`` snaps to divisors).
@@ -53,13 +53,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.direct_conv import apply_activation
 
 __all__ = [
     "halo_dims", "halo_window_spec", "weight_spec", "tile_spec", "bias_spec",
-    "gap_spec", "tap_windows", "first_step", "last_step", "epilogue_flush",
-    "gap_update", "tree_sum", "cotangent_prologue",
+    "lane_pad", "pad_lanes", "crop_lanes", "pencils", "unpencil",
+    "gap_spec", "window_steps", "tap_windows", "strided_source",
+    "compiler_params", "forward_semantics", "wgrad_semantics",
+    "first_step", "last_step", "epilogue_flush", "gap_update", "tree_sum",
+    "cotangent_prologue",
 ]
 
 # A map from the kernel's grid indices to the operand's leading block
@@ -86,18 +90,31 @@ def halo_window_spec(hib: int, wib: int, cb: int, hstep: int, wstep: int,
     """Overlapping input window over a blocked map ``[B, C/Cb, H, W, Cb]``.
 
     ``hstep``/``wstep`` are the *element* offsets between adjacent tiles'
-    windows (``hob * stride`` / ``wob * stride``); ``pick`` maps the grid ids
-    to ``(batch, channel_block, tile_h, tile_w)``.  Element-offset
-    (``pl.Unblocked``) indexing because adjacent windows overlap by the
-    filter halo — Blocked indexing only expresses multiples of the block
-    shape.
+    windows (``hob * stride`` / ``wob * stride``; 0 where the axis has one
+    tile — see ``window_steps``); ``pick`` maps the grid ids
+    to ``(batch, channel_block, tile_h, tile_w)``.  Element-offset indexing
+    because adjacent windows overlap by the filter halo — Blocked indexing
+    only expresses multiples of the block shape.  Every dim is a
+    ``pl.Element`` (Mosaic refuses a spec that mixes Element and Blocked
+    dims), so the index map returns element offsets on all five axes; the
+    unit batch and channel-block dims make those offsets the block ids.
     """
     def index_map(*ids):
         b, c, th, tw = pick(*ids)
-        return (b, c, th * hstep, tw * wstep, 0)
+        # one tile along an axis: a literal 0, which Mosaic can prove
+        # sublane-aligned (``tw * wstep`` it cannot, whatever wstep is)
+        return (b, c, th * hstep if hstep else 0, tw * wstep if wstep else 0,
+                0)
 
-    return pl.BlockSpec((1, 1, hib, wib, cb), index_map,
-                        indexing_mode=pl.Unblocked())
+    return pl.BlockSpec(tuple(pl.Element(d) for d in (1, 1, hib, wib, cb)),
+                        index_map)
+
+
+def window_steps(ho: int, wo: int, hob: int, wob: int,
+                 stride: int) -> Tuple[int, int]:
+    """``(hstep, wstep)`` for :func:`halo_window_spec`: the element offsets
+    between adjacent tiles, 0 for an axis the launch does not tile."""
+    return (hob * stride if ho > hob else 0, wob * stride if wo > wob else 0)
 
 
 def weight_spec(hf: int, wf: int, cib: int, cob: int,
@@ -125,54 +142,179 @@ def tile_spec(hob: int, wob: int, cb: int, pick: GridPick) -> pl.BlockSpec:
 
 
 def bias_spec(cob: int, pick: GridPick) -> pl.BlockSpec:
-    """One ``[1, Cob]`` bias pencil; ``pick`` -> (co_block,).  Also serves
-    the fused bias-*gradient* output (``db``): its ``[Co/Cob, Cob]`` layout
-    is the bias layout and its index map is constant along the wgrad
-    reduction axes, so the flush-once revisit discipline applies."""
+    """One ``[1, 1, Cob]`` bias pencil of a bias passed as ``[Co/Cob, 1,
+    Cob]`` (``pencils``: a block's two minor dims must be whole or
+    (8, 128)-aligned, and a ``[1, Cob]`` block of ``[Co/Cob, Cob]`` is
+    neither once ``Co/Cob > 1``); ``pick`` -> (co_block,).  Also serves
+    the fused bias-*gradient* output (``db``): its index map is constant
+    along the wgrad reduction axes, so the flush-once revisit discipline
+    applies."""
     def index_map(*ids):
         (co,) = pick(*ids)
-        return (co, 0)
-
-    return pl.BlockSpec((1, cob), index_map)
-
-
-def gap_spec(cob: int, pick: GridPick) -> pl.BlockSpec:
-    """One ``[1, 1, Cob]`` pooled-feature pencil of the fused GAP output
-    ``[N, Co/Cob, Cob]``; ``pick`` -> (batch, co_block).  The index map is
-    constant along the spatial-tile and reduction axes — the pooled block
-    is revisited and written once by ``gap_update``'s last-tile guard."""
-    def index_map(*ids):
-        b, co = pick(*ids)
-        return (b, co, 0)
+        return (co, 0, 0)
 
     return pl.BlockSpec((1, 1, cob), index_map)
 
 
-def tap_windows(x: jnp.ndarray, hf: int, wf: int, hob: int, wob: int,
+def pencils(b: jnp.ndarray) -> jnp.ndarray:
+    """``[..., Co/Cob, Cob]`` -> ``[..., Co/Cob, 1, Cob]``: the kernel-side
+    shape of a per-block pencil operand or output (bias, db, pooled GAP
+    features), so its block is whole in the two minor dims."""
+    return b.reshape(b.shape[:-1] + (1, b.shape[-1]))
+
+
+def unpencil(out, flag: bool):
+    """Undo :func:`pencils` on the pencil output of a ``(main, pencil)``
+    launch result when ``flag`` (the launch had one)."""
+    if not flag:
+        return out
+    main, pen = out
+    return main, pen.reshape(pen.shape[:-2] + pen.shape[-1:])
+
+
+def lane_pad(machine, interpret: bool, *pencils: int) -> Tuple[int, ...]:
+    """Zero lanes each channel pencil gains for a compiled launch.
+
+    Mosaic windows a block by element offset, and loads it with a stride,
+    only when its minor dim fills whole lane tiles — so on the chip a
+    narrow pencil (a first layer's Cb = 3, MobileNet's 32/64-channel
+    maps) is zero-padded to the machine's lane tile for the launch and the
+    pad is cropped off the result (:func:`crop_lanes`).  The model's
+    widths and stored weights are unchanged; the padded lanes are zeros in
+    and cropped out.  Interpret mode has no tiles and pads nothing."""
+    if interpret or machine.tile is None:
+        return (0,) * len(pencils)
+    lanes = machine.tile[1]
+    return tuple(-p % lanes for p in pencils)
+
+
+def pad_lanes(t, k: int, axis: int = -1):
+    """Zero-pad ``t`` (None passes through) by ``k`` at the end of
+    ``axis``."""
+    if t is None or not k:
+        return t
+    pad = [(0, 0)] * t.ndim
+    pad[axis] = (0, k)
+    return jnp.pad(t, pad)
+
+
+def crop_lanes(out, cb: int, gap: bool, nblk: int):
+    """Crop the lane pad of a padded launch's result back to ``cb`` lanes
+    per pencil: a blocked map, or with ``gap`` the flat pooled
+    ``[N, nblk * padded]`` features."""
+    if gap:
+        n = out.shape[0]
+        if out.shape[1] == nblk * cb:
+            return out
+        return out.reshape(n, nblk, -1)[..., :cb].reshape(n, nblk * cb)
+    return out if out.shape[-1] == cb else out[..., :cb]
+
+
+def gap_spec(cob: int, pick: GridPick) -> pl.BlockSpec:
+    """One ``[1, 1, 1, Cob]`` pooled-feature pencil of the fused GAP
+    output, laid out ``[N, Co/Cob, 1, Cob]`` (see :func:`pencils`);
+    ``pick`` -> (batch, co_block).  The index map is constant along the
+    spatial-tile and reduction axes — the pooled block is revisited and
+    written once by ``gap_update``'s last-tile guard."""
+    def index_map(*ids):
+        b, co = pick(*ids)
+        return (b, co, 0, 0)
+
+    return pl.BlockSpec((1, 1, 1, cob), index_map)
+
+
+def tap_windows(x, hf: int, wf: int, hob: int, wob: int,
                 stride: int = 1,
-                dilation: Tuple[int, int] = (1, 1),
+                dilation: Tuple[int, int] = (1, 1), dtype=None,
+                lead: Tuple[int, ...] = (),
                 ) -> Iterator[Tuple[Tuple[int, int], jnp.ndarray]]:
     """Yield ``((dh, dw), window[hob*wob, cb])`` for every filter tap.
 
-    ``x`` is the resident ``[Hib, Wib, Cb]`` input patch; each window is a
-    *strided VMEM view* (``lax.slice``) — these are the rows of the im2col
-    matrix, never copied out of the already-resident patch.  The unrolled
-    (dh, dw) loop is the paper's n, m loops (``Hf*Wf`` is small).  Tap
-    ``(dh, dw)`` starts at element offset ``(dh*dil_h, dw*dil_w)`` — the
-    whole dilation story for forward kernels is this one stride on the tap
-    origin.
+    ``x`` is the resident ``[Hib, Wib, Cb]`` input patch, a VMEM ref (or,
+    at stride 1, an already-loaded value); ``lead`` indexes the patch out
+    of a ref with unit leading dims (a block ``[1, 1, Hib, Wib, Cb]`` is
+    read as ``lead=(0, 0)`` — one indexer, never a ``.at`` view, whose
+    unpadded shape Mosaic cannot slice for packed bf16).  Each window is a
+    load straight out of it — these are the rows of the im2col matrix,
+    never copied out of the already-resident patch.  Strided windows are
+    strided *ref loads* (``pl.ds(..., stride=s)``): Mosaic lowers no
+    strided slice of a loaded value, and its strided load takes 32-bit
+    data only (see ``strided_source``).  ``dtype`` casts each window (the
+    narrow operand dtype of a widened source).  The unrolled (dh, dw) loop
+    is the paper's n, m loops (``Hf*Wf`` is small).  Tap ``(dh, dw)``
+    starts at element offset ``(dh*dil_h, dw*dil_w)`` — the whole dilation
+    story for forward kernels is this one stride on the tap origin.
     """
     cb = x.shape[-1]
     dil_h, dil_w = dilation
     for dh in range(hf):
         for dw in range(wf):
             oh, ow = dh * dil_h, dw * dil_w
-            win = jax.lax.slice(
-                x, (oh, ow, 0),
-                (oh + (hob - 1) * stride + 1, ow + (wob - 1) * stride + 1,
-                 cb),
-                (stride, stride, 1))
+            if stride == 1:
+                win = x[lead + (slice(oh, oh + hob), slice(ow, ow + wob),
+                                slice(None))]
+            else:
+                win = x[lead + (pl.ds(oh, hob, stride=stride),
+                                pl.ds(ow, wob, stride=stride), slice(None))]
+            if dtype is not None:
+                win = win.astype(dtype)
             yield (dh, dw), win.reshape(hob * wob, cb)
+
+
+def strided_source(ref, stride: int, body: Callable,
+                   lead: Tuple[int, ...] = (0, 0)):
+    """Run ``body(src, dtype, lead)`` with a tap source for the
+    ``[Hib, Wib, Cb]`` patch ``ref[lead]`` that ``tap_windows`` can read at
+    ``stride``.
+
+    Mosaic's strided load takes 32-bit data only, so a strided launch over
+    narrower (bf16) operands first widens the patch once into a scoped f32
+    VMEM copy — exact, and the taps narrow each window back to the operand
+    dtype before the MXU sees it (``tap_windows(dtype=...)``).  Stride-1
+    and 32-bit launches read ``ref`` directly.  ``body`` gets
+    ``(src, dtype, lead)``: the source, the dtype its windows are cast to
+    and the leading index of the patch in it; its return value is
+    returned.
+    """
+    if stride == 1 or ref.dtype.itemsize == 4:
+        return body(ref, None, lead)
+
+    def scoped(buf):
+        buf[...] = ref[lead].astype(jnp.float32)
+        return body(buf, ref.dtype, ())
+
+    shape = ref.shape[len(lead):]
+    return pl.run_scoped(scoped, pltpu.VMEM(shape, jnp.float32))
+
+
+def compiler_params(machine, semantics: Sequence[str]):
+    """The Mosaic parameters every launch of the family sets: the grid's
+    ``dimension_semantics`` ("parallel" axes carry no cross-step state;
+    "arbitrary" ones are reductions or revisit an output block) and the
+    scoped-VMEM limit of the ``MachineModel`` the tiles were fitted
+    against — so a tile the blocking model admits is also a tile the
+    compiler may allocate (DESIGN.md §17).  A model without a limit (the
+    CPU test machines) leaves the compiler's default."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(semantics),
+        vmem_limit_bytes=machine.vmem_limit_bytes or None)
+
+
+def forward_semantics(gap: bool, reduction: bool = True) -> Tuple[str, ...]:
+    """``dimension_semantics`` of a forward grid ``(n, co, th, tw[, ci])``:
+    a fused GAP revisits its pooled pencil across the spatial-tile axes,
+    which makes them "arbitrary"; the channel reduction ``ci`` always is."""
+    spatial = ("arbitrary",) * 2 if gap else ("parallel",) * 2
+    return ("parallel", "parallel") + spatial + (
+        ("arbitrary",) if reduction else ())
+
+
+def wgrad_semantics(with_db: bool) -> Tuple[str, ...]:
+    """``dimension_semantics`` of a weight-gradient grid ``(co, ci, n, th,
+    tw)``: a fused ``db`` pencil is revisited across ``ci`` as well as
+    across the reduction axes."""
+    return ("parallel", "arbitrary" if with_db else "parallel",
+            "arbitrary", "arbitrary", "arbitrary")
 
 
 def first_step(axes: Sequence[int]):
@@ -217,7 +359,7 @@ def epilogue_flush(o_ref, acc: jnp.ndarray, hob: int, wob: int,
         "stay f32 under every precision policy")
     out = acc
     if b_ref is not None:
-        out = out + b_ref[...].astype(jnp.float32)       # (1, Cob) broadcast
+        out = out + b_ref[0].astype(jnp.float32)         # (1, Cob) broadcast
     out = apply_activation(out, activation)
     cb = o_ref.shape[-1]
     if r_ref is not None:
@@ -290,7 +432,7 @@ def gap_update(g_ref, gacc_ref, tile: jnp.ndarray, hw: int,
 
     @pl.when(is_last)
     def _pool():
-        g_ref[0] = (gacc_ref[...] * inv_hw).astype(g_ref.dtype)
+        g_ref[0, 0] = (gacc_ref[...] * inv_hw).astype(g_ref.dtype)
 
 
 def cotangent_prologue(g: jnp.ndarray, z, activation: Optional[str],

@@ -48,7 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.blocking import (MachineModel, TPU_V5E,
+from repro.core.backend import resolve_interpret, resolve_machine
+from repro.core.blocking import (MachineModel,
                                  choose_depthwise_blocking,
                                  choose_depthwise_wgrad_blocking,
                                  dgrad_extents)
@@ -56,10 +57,12 @@ from repro.core.conv_baselines import Padding
 from repro.core.convspec import ConvSpec
 from repro.core.direct_conv import apply_activation, pad_blocked
 from repro.core.precision import F32, Precision, resolve_precision
-from .conv2d_common import (bias_spec, cotangent_prologue, epilogue_flush,
-                            first_step, gap_spec, gap_update, halo_dims,
-                            halo_window_spec, last_step, tap_windows,
-                            tile_spec, weight_spec)
+from .conv2d_common import (bias_spec, compiler_params, cotangent_prologue,
+                            crop_lanes, epilogue_flush, first_step,
+                            forward_semantics, gap_spec, gap_update,
+                            halo_dims, halo_window_spec, lane_pad,
+                            last_step, pad_lanes, pencils, strided_source,
+                            tap_windows, tile_spec, unpencil, window_steps)
 
 __all__ = ["depthwise_conv2d_blocked_pallas", "depthwise_dgrad_pallas",
            "depthwise_wgrad_pallas"]
@@ -86,16 +89,23 @@ def _dw_fwd_kernel(x_ref, w_ref, *rest, hf, wf, hob, wob, stride, dilation,
     g_ref = rest.pop(0) if has_gap else None
     gacc_ref = rest.pop(0) if has_gap else None
 
-    patch = x_ref[0, 0]
+    patch, lead = x_ref, (0, 0)
     if z_ref is not None:
-        patch = cotangent_prologue(patch, z_ref[0, 0], prologue_activation)
+        patch, lead = cotangent_prologue(x_ref[0, 0], z_ref[0, 0],
+                                         prologue_activation), ()
 
-    # no reduction axis: the accumulator is born and flushed in one step
-    acc = jnp.zeros((hob * wob, x_ref.shape[-1]), jnp.float32)
-    for (dh, dw), win in tap_windows(patch, hf, wf, hob, wob, stride,
-                                     dilation):
-        wtap = w_ref[0, 0, dh, dw, 0]                    # [Cb] — own lane only
-        acc = acc + win.astype(jnp.float32) * wtap.astype(jnp.float32)[None, :]
+    def taps(src, _, lead):
+        # no reduction axis: the accumulator is born and flushed in one step
+        # (the taps compute in f32, so a widened source is read as is)
+        acc = jnp.zeros((hob * wob, x_ref.shape[-1]), jnp.float32)
+        for (dh, dw), win in tap_windows(src, hf, wf, hob, wob, stride,
+                                         dilation, lead=lead):
+            wtap = w_ref[0, 0, dh, dw, 0]          # [Cb] — own lane only
+            acc = acc + (win.astype(jnp.float32)
+                         * wtap.astype(jnp.float32)[None, :])
+        return acc
+
+    acc = strided_source(patch, stride, taps, lead)
     tile = epilogue_flush(o_ref, acc, hob, wob, b_ref, activation, r_ref)
     if has_gap:
         gap_update(g_ref, gacc_ref, tile, hw,
@@ -136,12 +146,15 @@ def _dw_wgrad_kernel(x_ref, dy_ref, *rest, hf, wf, hob, wob,
 
         @pl.when(last_step((1, 2, 3)))
         def _db_flush():
-            db_ref[0] = dbacc_ref[0].astype(db_ref.dtype)
+            db_ref[0] = dbacc_ref[...].astype(db_ref.dtype)
 
-    for (dh, dw), win in tap_windows(x_ref[0, 0], hf, wf, hob, wob, stride,
-                                     dilation):
-        acc_ref[dh * wf + dw] = acc_ref[dh * wf + dw] + jnp.sum(
-            win.astype(jnp.float32) * dy, axis=0)
+    def taps(src, _, lead):
+        for (dh, dw), win in tap_windows(src, hf, wf, hob, wob, stride,
+                                         dilation, lead=lead):
+            acc_ref[dh * wf + dw] = acc_ref[dh * wf + dw] + jnp.sum(
+                win.astype(jnp.float32) * dy, axis=0)
+
+    strided_source(x_ref, stride, taps)
 
     @pl.when(last_step((1, 2, 3)))
     def _flush():
@@ -175,11 +188,12 @@ def _dw_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
     hob, wob = blk.hob, blk.wob
     hib, wib = halo_dims(hob, wob, hf, wf, stride, dilation)
 
+    steps = window_steps(ho, wo, hob, wob, stride)
     has_bias = bias is not None
     has_z = z is not None
     operands = [xp, w]
     in_specs = [
-        halo_window_spec(hib, wib, cb, hob * stride, wob * stride,
+        halo_window_spec(hib, wib, cb, *steps,
                          lambda b, c, th, tw: (b, c, th, tw)),
         # the weight "matrix" axes are the two unit dims; same blocked
         # layout, Cig=1 extreme
@@ -190,10 +204,10 @@ def _dw_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
         assert z.shape == xp.shape, (z.shape, xp.shape)
         operands.append(z)
         in_specs.append(
-            halo_window_spec(hib, wib, cb, hob * stride, wob * stride,
+            halo_window_spec(hib, wib, cb, *steps,
                              lambda b, c, th, tw: (b, c, th, tw)))
     if has_bias:
-        operands.append(bias)
+        operands.append(pencils(bias))
         in_specs.append(bias_spec(cb, lambda b, c, th, tw: (c,)))
     if residual is not None:
         assert residual.shape == (n, cblk, ho, wo, cb), \
@@ -208,11 +222,11 @@ def _dw_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
     if gap:
         out_specs = [out_specs, gap_spec(cb, lambda b, c, th, tw: (b, c))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((n, cblk, cb), xp.dtype)]
+                     jax.ShapeDtypeStruct((n, cblk, 1, cb), xp.dtype)]
         scratch.append(pltpu.VMEM((1, cb), jnp.float32))
 
     grid = (n, cblk, ho // hob, wo // wob)
-    return pl.pallas_call(
+    return unpencil(pl.pallas_call(
         partial(_dw_fwd_kernel, hf=hf, wf=wf, hob=hob, wob=wob,
                 stride=stride, dilation=dilation, activation=activation,
                 has_bias=has_bias, has_z=has_z,
@@ -223,8 +237,10 @@ def _dw_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=compiler_params(
+            machine, forward_semantics(gap, reduction=False)),
         interpret=interpret,
-    )(*operands)
+    )(*operands), gap)
 
 
 @partial(jax.jit, static_argnames=("stride", "hob", "wob", "machine",
@@ -232,8 +248,8 @@ def _dw_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
 def depthwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
                            hob: Optional[int] = None,
                            wob: Optional[int] = None,
-                           machine: MachineModel = TPU_V5E,
-                           interpret: bool = False,
+                           machine: Optional[MachineModel] = None,
+                           interpret: Optional[bool] = None,
                            dilation=(1, 1),
                            z: Optional[jnp.ndarray] = None,
                            activation: Optional[str] = None) -> jnp.ndarray:
@@ -250,6 +266,8 @@ def depthwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
     pre-activation map (same shape as ``dy``), dilated and padded alongside
     the cotangent so the kernel forms ``dz = g * act'(z)`` on tile load —
     the dilation zeros stay zero because the prologue is elementwise."""
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     n, cblk, ho, wo, cb = dy.shape
     _, _, hf, wf, _, _ = w.shape
     dil_h, dil_w = dilation
@@ -279,8 +297,8 @@ def depthwise_wgrad_pallas(xp: jnp.ndarray, dy: jnp.ndarray,
                            hf: int, wf: int, stride: int = 1,
                            hob: Optional[int] = None,
                            wob: Optional[int] = None,
-                           machine: MachineModel = TPU_V5E,
-                           interpret: bool = False,
+                           machine: Optional[MachineModel] = None,
+                           interpret: Optional[bool] = None,
                            out_dtype=None,
                            dilation=(1, 1),
                            z: Optional[jnp.ndarray] = None,
@@ -298,6 +316,8 @@ def depthwise_wgrad_pallas(xp: jnp.ndarray, dy: jnp.ndarray,
     ``dy``'s shape — the saved pre-activation).  ``with_db`` additionally
     returns ``(dw, db)`` with ``db = Σ dz`` accumulated f32 in-kernel,
     shape ``[C/Cb, Cb]``."""
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     n, cblk, hi, wi, cb = xp.shape
     n2, cblk2, ho, wo, cb2 = dy.shape
     assert (n, cblk, cb) == (n2, cblk2, cb2), (xp.shape, dy.shape)
@@ -312,7 +332,8 @@ def depthwise_wgrad_pallas(xp: jnp.ndarray, dy: jnp.ndarray,
     has_z = z is not None
     operands = [xp, dy]
     in_specs = [
-        halo_window_spec(hib, wib, cb, hob * stride, wob * stride,
+        halo_window_spec(hib, wib, cb,
+                         *window_steps(ho, wo, hob, wob, stride),
                          lambda c, b, th, tw: (b, c, th, tw)),
         tile_spec(hob, wob, cb, lambda c, b, th, tw: (b, c, th, tw)),
     ]
@@ -330,11 +351,11 @@ def depthwise_wgrad_pallas(xp: jnp.ndarray, dy: jnp.ndarray,
     if with_db:
         out_specs = [out_specs, bias_spec(cb, lambda c, b, th, tw: (c,))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((cblk, cb), jnp.float32)]
+                     jax.ShapeDtypeStruct((cblk, 1, cb), jnp.float32)]
         scratch.append(pltpu.VMEM((1, cb), jnp.float32))
 
     grid = (cblk, n, ho // hob, wo // wob)
-    return pl.pallas_call(
+    return unpencil(pl.pallas_call(
         partial(_dw_wgrad_kernel, hf=hf, wf=wf, hob=hob, wob=wob,
                 stride=stride, dilation=dilation, has_z=has_z,
                 activation=activation, with_db=with_db),
@@ -343,8 +364,10 @@ def depthwise_wgrad_pallas(xp: jnp.ndarray, dy: jnp.ndarray,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=compiler_params(
+            machine, ("parallel",) + ("arbitrary",) * 3),
         interpret=interpret,
-    )(*operands)
+    )(*operands), with_db)
 
 
 # ---------------------------------------------------------------------------
@@ -451,8 +474,8 @@ def depthwise_conv2d_blocked_pallas(x: jnp.ndarray, w: jnp.ndarray,
                                     activation: Optional[str] = None,
                                     hob: Optional[int] = None,
                                     wob: Optional[int] = None,
-                                    machine: MachineModel = TPU_V5E,
-                                    interpret: bool = False,
+                                    machine: Optional[MachineModel] = None,
+                                    interpret: Optional[bool] = None,
                                     precision: Precision | str = F32,
                                     dilation: int | tuple = 1,
                                     residual: Optional[jnp.ndarray] = None,
@@ -472,10 +495,15 @@ def depthwise_conv2d_blocked_pallas(x: jnp.ndarray, w: jnp.ndarray,
     the depthwise working set (no weight matrix, no reduction) fits VMEM
     wherever the dense window kernel's does.
     """
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     n, cblk, hi, wi, cb = x.shape
-    c = cblk * cb
+    (p,) = lane_pad(machine, interpret, cb)
+    c = cblk * (cb + p)
     spec = ConvSpec.make(n, hi, wi, c, c, w.shape[2], w.shape[3],
                          stride=stride, padding=padding, groups=c,
                          dilation=dilation)
-    return _dwconv(x, w, bias, residual, spec, activation, hob, wob, machine,
-                   interpret, resolve_precision(precision), gap)
+    out = _dwconv(pad_lanes(x, p), pad_lanes(w, p), pad_lanes(bias, p),
+                  pad_lanes(residual, p), spec, activation, hob, wob,
+                  machine, interpret, resolve_precision(precision), gap)
+    return crop_lanes(out, cb, gap, cblk)

@@ -3,7 +3,7 @@
 A 1x1 stride-1 unpadded dense conv is a channel matmul applied at every
 spatial position — ``ConvSpec.is_pointwise``.  The window kernel computes
 it correctly but drags the halo machinery along for a halo of size zero:
-``pl.Unblocked`` element-offset indexing, one strided ``tap_windows`` view,
+element-offset (``pl.Element``) indexing, one strided ``tap_windows`` view,
 a ``(Hob-1)*stride + 1`` window that is exactly the tile.  This family
 strips all of it: plain Blocked BlockSpecs, one MXU matmul per grid step.
 
@@ -35,15 +35,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.blocking import (MachineModel, TPU_V5E,
+from repro.core.backend import resolve_interpret, resolve_machine
+from repro.core.blocking import (MachineModel,
                                  choose_pointwise_blocking,
                                  choose_pointwise_wgrad_blocking)
 from repro.core.direct_conv import apply_activation
 from repro.core.padding import normalize_padding
 from repro.core.precision import F32, Precision, resolve_precision
-from .conv2d_common import (bias_spec, cotangent_prologue, epilogue_flush,
-                            first_step, gap_spec, gap_update, last_step,
-                            tile_spec, weight_spec)
+from .conv2d_common import (bias_spec, compiler_params, cotangent_prologue,
+                            epilogue_flush, first_step, forward_semantics,
+                            gap_spec, gap_update, last_step, pencils,
+                            tile_spec, unpencil, weight_spec,
+                            wgrad_semantics)
 
 __all__ = ["pointwise_conv2d_blocked_pallas", "pointwise_dgrad_pallas",
            "pointwise_wgrad_pallas"]
@@ -147,7 +150,7 @@ def _pw_wgrad_kernel(x_ref, dy_ref, *rest, hob, wob, has_z, activation,
 
         @pl.when(last_step((1, 2, 3, 4)))
         def _db_flush():
-            db_ref[0] = dbacc_ref[0].astype(db_ref.dtype)
+            db_ref[0] = dbacc_ref[...].astype(db_ref.dtype)
 
     # [Hob*Wob, Cib] x [Hob*Wob, Cob] -> [Cib, Cob]
     acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
@@ -182,12 +185,12 @@ def _pw_forward(x: jnp.ndarray, w: jnp.ndarray, bias, activation, hob, wob,
     operands = [x, w]
     in_specs = [
         # plain Blocked tiles — the whole point of the fast path: no
-        # Unblocked element-offset window, no halo
+        # element-offset window, no halo
         tile_spec(hob, wob, cib, lambda b, co, th, tw, ci: (b, ci, th, tw)),
         weight_spec(1, 1, cib, cob, lambda b, co, th, tw, ci: (co, ci)),
     ]
     if has_bias:
-        operands.append(bias)
+        operands.append(pencils(bias))
         in_specs.append(bias_spec(cob, lambda b, co, th, tw, ci: (co,)))
     if residual is not None:
         assert residual.shape == (n, coblk, hi, wi, cob), \
@@ -204,11 +207,11 @@ def _pw_forward(x: jnp.ndarray, w: jnp.ndarray, bias, activation, hob, wob,
         out_specs = [out_specs,
                      gap_spec(cob, lambda b, co, th, tw, ci: (b, co))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((n, coblk, cob), x.dtype)]
+                     jax.ShapeDtypeStruct((n, coblk, 1, cob), x.dtype)]
         scratch.append(pltpu.VMEM((1, cob), jnp.float32))
 
     grid = (n, coblk, hi // hob, wi // wob, ciblk)
-    return pl.pallas_call(
+    return unpencil(pl.pallas_call(
         partial(_pw_fwd_kernel, hob=hob, wob=wob, activation=activation,
                 has_bias=has_bias, has_residual=residual is not None,
                 has_gap=gap, hw=hi * wi),
@@ -217,8 +220,9 @@ def _pw_forward(x: jnp.ndarray, w: jnp.ndarray, bias, activation, hob, wob,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=compiler_params(machine, forward_semantics(gap)),
         interpret=interpret,
-    )(*operands)
+    )(*operands), gap)
 
 
 @partial(jax.jit, static_argnames=("hob", "wob", "machine", "interpret",
@@ -226,8 +230,8 @@ def _pw_forward(x: jnp.ndarray, w: jnp.ndarray, bias, activation, hob, wob,
 def pointwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
                            hob: Optional[int] = None,
                            wob: Optional[int] = None,
-                           machine: MachineModel = TPU_V5E,
-                           interpret: bool = False,
+                           machine: Optional[MachineModel] = None,
+                           interpret: Optional[bool] = None,
                            z: Optional[jnp.ndarray] = None,
                            activation: Optional[str] = None) -> jnp.ndarray:
     """Input gradient of the pointwise conv — the transposed channel matmul.
@@ -235,6 +239,8 @@ def pointwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
 
     ``z``/``activation`` fuse the prologue ``dz = g * act'(z)`` on tile
     load (``z`` is the saved pre-activation, same shape as ``dy``)."""
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     n, coblk, ho, wo, cob = dy.shape
     coblk2, ciblk, one, one2, cib, cob2 = w.shape
     assert (coblk, cob) == (coblk2, cob2) and one == one2 == 1, \
@@ -274,6 +280,8 @@ def pointwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
                             lambda b, ci, th, tw, co: (b, ci, th, tw)),
         out_shape=jax.ShapeDtypeStruct((n, ciblk, ho, wo, cib), dy.dtype),
         scratch_shapes=[pltpu.VMEM((hob * wob, cib), jnp.float32)],
+        compiler_params=compiler_params(
+            machine, ("parallel",) * 4 + ("arbitrary",)),
         interpret=interpret,
     )(*operands)
 
@@ -283,8 +291,8 @@ def pointwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
 def pointwise_wgrad_pallas(x: jnp.ndarray, dy: jnp.ndarray,
                            hob: Optional[int] = None,
                            wob: Optional[int] = None,
-                           machine: MachineModel = TPU_V5E,
-                           interpret: bool = False,
+                           machine: Optional[MachineModel] = None,
+                           interpret: Optional[bool] = None,
                            out_dtype=None,
                            z: Optional[jnp.ndarray] = None,
                            activation: Optional[str] = None,
@@ -295,6 +303,8 @@ def pointwise_wgrad_pallas(x: jnp.ndarray, dy: jnp.ndarray,
     ``z``/``activation`` fuse ``dz = g * act'(z)`` on tile load;
     ``with_db`` additionally returns ``(dw, db)`` with ``db = Σ dz``
     accumulated f32 in-kernel, shape ``[Co/Cob, Cob]``."""
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     n, ciblk, hi, wi, cib = x.shape
     n2, coblk, ho, wo, cob = dy.shape
     assert (n, hi, wi) == (n2, ho, wo), (x.shape, dy.shape)
@@ -328,11 +338,11 @@ def pointwise_wgrad_pallas(x: jnp.ndarray, dy: jnp.ndarray,
         out_specs = [out_specs,
                      bias_spec(cob, lambda co, ci, b, th, tw: (co,))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((coblk, cob), jnp.float32)]
+                     jax.ShapeDtypeStruct((coblk, 1, cob), jnp.float32)]
         scratch.append(pltpu.VMEM((1, cob), jnp.float32))
 
     grid = (coblk, ciblk, n, ho // hob, wo // wob)
-    return pl.pallas_call(
+    return unpencil(pl.pallas_call(
         partial(_pw_wgrad_kernel, hob=hob, wob=wob, has_z=has_z,
                 activation=activation, with_db=with_db),
         grid=grid,
@@ -340,8 +350,9 @@ def pointwise_wgrad_pallas(x: jnp.ndarray, dy: jnp.ndarray,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=compiler_params(machine, wgrad_semantics(with_db)),
         interpret=interpret,
-    )(*operands)
+    )(*operands), with_db)
 
 
 # ---------------------------------------------------------------------------
@@ -430,8 +441,8 @@ def pointwise_conv2d_blocked_pallas(x: jnp.ndarray, w: jnp.ndarray,
                                     activation: Optional[str] = None,
                                     hob: Optional[int] = None,
                                     wob: Optional[int] = None,
-                                    machine: MachineModel = TPU_V5E,
-                                    interpret: bool = False,
+                                    machine: Optional[MachineModel] = None,
+                                    interpret: Optional[bool] = None,
                                     precision: Precision | str = F32,
                                     residual: Optional[jnp.ndarray] = None,
                                     gap: bool = False):
@@ -449,6 +460,8 @@ def pointwise_conv2d_blocked_pallas(x: jnp.ndarray, w: jnp.ndarray,
     (``ConvSpec.is_pointwise``); anything else belongs to the window
     family and raises here.
     """
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     if w.shape[2] != 1 or w.shape[3] != 1:
         raise ValueError(f"pointwise kernel needs a 1x1 filter, got "
                          f"{w.shape[2]}x{w.shape[3]}")

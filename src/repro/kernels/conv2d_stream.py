@@ -57,8 +57,11 @@ from repro.core.blocking import (MachineModel, choose_stream_blocking,
                                  choose_stream_wgrad_blocking, dgrad_extents)
 from repro.core.direct_conv import pad_blocked
 from repro.utils.faults import inject as _inject_fault
-from .conv2d_common import (bias_spec, epilogue_flush, first_step, gap_spec,
-                            gap_update, last_step, tap_windows, tile_spec)
+from .conv2d_common import (bias_spec, compiler_params, epilogue_flush,
+                            first_step, forward_semantics, gap_spec,
+                            gap_update, last_step,
+                            pencils, strided_source, tap_windows, tile_spec,
+                            unpencil)
 
 __all__ = ["stream_forward", "stream_dgrad", "stream_wgrad"]
 
@@ -107,7 +110,11 @@ def _stream_conv_kernel(x_any, w_any, *rest, hf, wf, hob, wob, hso, stride,
     hin, wib, halo = _strip_geometry(hso, wob, hf, wf, stride)
     nstrips = hob // hso
     row0 = th * hob * stride
-    col0 = tw * wob * stride
+    # a launch with one column tile copies whole rows: a ``pl.ds`` of the
+    # full width is still a slice Mosaic must prove tile-aligned, which it
+    # cannot for packed bf16 rows of unaligned width
+    cols = (pl.ds(tw * wob * stride, wib) if wib < x_any.shape[3]
+            else slice(None))
 
     # weights: one DMA into singly-resident scratch — the streamed variant's
     # headline saving (the window path pays 2x for Pallas pipelining)
@@ -122,7 +129,7 @@ def _stream_conv_kernel(x_any, w_any, *rest, hf, wf, hob, wob, hso, stride,
         lo = 0 if k == 0 else halo
         return pltpu.make_async_copy(
             x_any.at[b, red, pl.ds(row0 + k * hso * stride + lo, hin - lo),
-                     pl.ds(col0, wib), :],
+                     cols, :],
             ring.at[k % 2, pl.ds(lo, hin - lo)], sem.at[k % 2])
 
     @pl.when(first_step((4,)))
@@ -141,19 +148,22 @@ def _stream_conv_kernel(x_any, w_any, *rest, hf, wf, hob, wob, hso, stride,
             if halo:
                 ring[(k + 1) % 2, 0:halo] = ring[k % 2, hin - halo:hin]
             strip_dma(k + 1).start()          # in flight while k contracts
-        acc = acc_ref[k * hso * wob:(k + 1) * hso * wob]
-        for (dh, dw), win in tap_windows(ring[k % 2], hf, wf, hso, wob,
-                                         stride):
-            if transpose:
-                # [Hso*Wob, Cob] x [Cib, Cob] -> [Hso*Wob, Cib]
-                acc = acc + jax.lax.dot_general(
-                    win, wgt[hf - 1 - dh, wf - 1 - dw],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            else:
-                acc = acc + jnp.dot(win, wgt[dh, dw],
-                                    preferred_element_type=jnp.float32)
-        acc_ref[k * hso * wob:(k + 1) * hso * wob] = acc
+        rows = pl.ds(k * hso * wob, hso * wob)
+
+        def taps(src, dtype, lead, rows=rows):
+            for (dh, dw), win in tap_windows(src, hf, wf, hso, wob, stride,
+                                             dtype=dtype, lead=lead):
+                if transpose:
+                    # [Hso*Wob, Cob] x [Cib, Cob] -> [Hso*Wob, Cib]
+                    acc_ref[rows] += jax.lax.dot_general(
+                        win, wgt[hf - 1 - dh, wf - 1 - dw],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                else:
+                    acc_ref[rows] += jnp.dot(
+                        win, wgt[dh, dw], preferred_element_type=jnp.float32)
+
+        strided_source(ring, stride, taps, (k % 2,))
 
     gap_first = first_step((2, 3)) if has_gap else None
     gap_last = last_step((2, 3)) if has_gap else None
@@ -168,7 +178,7 @@ def _stream_conv_kernel(x_any, w_any, *rest, hf, wf, hob, wob, hso, stride,
 
 def _any_spec() -> pl.BlockSpec:
     """A whole-array operand left in HBM for the kernel's manual DMA."""
-    return pl.BlockSpec(memory_space=pltpu.ANY)
+    return pl.BlockSpec(memory_space=pltpu.HBM)
 
 
 def stream_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
@@ -211,7 +221,7 @@ def stream_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
     operands = [xp, w]
     in_specs = [_any_spec(), _any_spec()]
     if has_bias:
-        operands.append(bias)
+        operands.append(pencils(bias))
         in_specs.append(bias_spec(cob, lambda b, co, th, tw, ci: (co,)))
     if has_residual:
         assert residual.shape == (n, coblk, ho, wo, cob), \
@@ -230,12 +240,12 @@ def stream_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
         out_specs = [out_specs,
                      gap_spec(cob, lambda b, co, th, tw, ci: (b, co))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((n, coblk, cob), xp.dtype)]
+                     jax.ShapeDtypeStruct((n, coblk, 1, cob), xp.dtype)]
         scratch.append(pltpu.VMEM((1, cob), jnp.float32))
     scratch.append(pltpu.SemaphoreType.DMA((3,)))
 
     grid = (n, coblk, ho // hob, wo // wob, ciblk)
-    return pl.pallas_call(
+    return unpencil(pl.pallas_call(
         partial(_stream_conv_kernel, hf=hf, wf=wf, hob=hob, wob=wob, hso=hso,
                 stride=stride, activation=activation, has_bias=has_bias,
                 has_residual=has_residual, has_gap=gap, hw=ho * wo,
@@ -245,8 +255,9 @@ def stream_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=compiler_params(machine, forward_semantics(gap)),
         interpret=interpret,
-    )(*operands)
+    )(*operands), gap)
 
 
 def stream_dgrad(dy: jnp.ndarray, w: jnp.ndarray, stride: int,
@@ -295,6 +306,8 @@ def stream_dgrad(dy: jnp.ndarray, w: jnp.ndarray, stride: int,
                         pltpu.VMEM((2, hin, wib, cob), dy.dtype),
                         pltpu.VMEM((hob * wob, cib), jnp.float32),
                         pltpu.SemaphoreType.DMA((3,))],
+        compiler_params=compiler_params(
+            machine, ("parallel",) * 4 + ("arbitrary",)),
         interpret=interpret,
     )(dyp, w)
 
@@ -315,18 +328,21 @@ def _stream_wgrad_kernel(x_any, dy_any, o_any, xring, dyring, acc_ref, sem,
     co, ci, b, tw = (pl.program_id(i) for i in range(4))
     hin, wib, halo = _strip_geometry(hso, wob, hf, wf, stride)
     nstrips = ho // hso
-    col0 = tw * wob * stride
+    # whole rows when there is one column tile (see _stream_conv_kernel)
+    xcols = (pl.ds(tw * wob * stride, wib) if wib < x_any.shape[3]
+             else slice(None))
+    dycols = pl.ds(tw * wob, wob) if wob < dy_any.shape[3] else slice(None)
 
     def x_dma(k: int):
         lo = 0 if k == 0 else halo
         return pltpu.make_async_copy(
             x_any.at[b, ci, pl.ds(k * hso * stride + lo, hin - lo),
-                     pl.ds(col0, wib), :],
+                     xcols, :],
             xring.at[k % 2, pl.ds(lo, hin - lo)], sem.at[0, k % 2])
 
     def dy_dma(k: int):
         return pltpu.make_async_copy(
-            dy_any.at[b, co, pl.ds(k * hso, hso), pl.ds(tw * wob, wob), :],
+            dy_any.at[b, co, pl.ds(k * hso, hso), dycols, :],
             dyring.at[k % 2], sem.at[1, k % 2])
 
     @pl.when(first_step((2, 3)))
@@ -344,12 +360,16 @@ def _stream_wgrad_kernel(x_any, dy_any, o_any, xring, dyring, acc_ref, sem,
             x_dma(k + 1).start()
             dy_dma(k + 1).start()
         dyf = dyring[k % 2].reshape(hso * wob, dyring.shape[-1])
-        for (dh, dw), win in tap_windows(xring[k % 2], hf, wf, hso, wob,
-                                         stride):
-            # [Hso*Wob, Cib] x [Hso*Wob, Cob] -> [Cib, Cob]
-            acc_ref[dh, dw] = acc_ref[dh, dw] + jax.lax.dot_general(
-                win, dyf, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+
+        def taps(src, dtype, lead, dyf=dyf):
+            for (dh, dw), win in tap_windows(src, hf, wf, hso, wob, stride,
+                                             dtype=dtype, lead=lead):
+                # [Hso*Wob, Cib] x [Hso*Wob, Cob] -> [Cib, Cob]
+                acc_ref[dh, dw] = acc_ref[dh, dw] + jax.lax.dot_general(
+                    win, dyf, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+        strided_source(xring, stride, taps, (k % 2,))
 
     @pl.when(last_step((2, 3)))
     def _flush():
@@ -394,6 +414,8 @@ def stream_wgrad(xp: jnp.ndarray, dy: jnp.ndarray, hf: int, wf: int,
                         pltpu.VMEM((hf, wf, cib, cob), jnp.float32),
                         pltpu.SemaphoreType.DMA((2, 2)),
                         pltpu.SemaphoreType.DMA(())],
+        compiler_params=compiler_params(
+            machine, ("parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(xp, dy)
     return out.astype(out_dtype or xp.dtype)

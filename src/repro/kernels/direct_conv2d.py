@@ -7,7 +7,7 @@
   wgrad     dw  = Σ_tiles  x_windowᵀ @ dŷ_tile       (weight gradient)
 
 All three are parameterized by the same ``core.blocking`` output and built
-from ``kernels.conv2d_common``: the halo'd ``pl.Unblocked`` input window,
+from ``kernels.conv2d_common``: the halo'd element-offset input window,
 the strided ``tap_windows`` VMEM views (the im2col rows that are never
 materialized), the reduction-axis init/flush guards and the fused epilogue.
 
@@ -75,7 +75,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.blocking import (MachineModel, TPU_V5E, choose_blocking,
+from repro.core.backend import resolve_interpret, resolve_machine
+from repro.core.blocking import (MachineModel, choose_blocking,
                                  choose_dgrad_blocking,
                                  choose_wgrad_blocking, dgrad_extents)
 from repro.core.conv_baselines import Padding
@@ -84,10 +85,13 @@ from repro.core.dispatch import KernelRoute, route_pallas, stream_flag
 from repro.core.direct_conv import apply_activation, pad_blocked
 from repro.core.precision import F32, Precision, resolve_precision
 from repro.utils.faults import inject as _inject_fault
-from .conv2d_common import (bias_spec, cotangent_prologue, epilogue_flush,
-                            first_step, gap_spec, gap_update, halo_dims,
-                            halo_window_spec, last_step, tap_windows,
-                            tile_spec, weight_spec)
+from .conv2d_common import (bias_spec, compiler_params, cotangent_prologue,
+                            crop_lanes, epilogue_flush, first_step,
+                            forward_semantics, gap_spec, gap_update,
+                            halo_dims, halo_window_spec, lane_pad, last_step,
+                            pad_lanes, pencils, strided_source, tap_windows,
+                            tile_spec, unpencil, weight_spec, wgrad_semantics,
+                            window_steps)
 from .conv2d_stream import stream_dgrad, stream_forward, stream_wgrad
 
 __all__ = ["direct_conv2d_blocked_pallas", "direct_conv2d_dgrad_pallas",
@@ -113,12 +117,13 @@ def _fwd_kernel(x_ref, w_ref, *rest, hf, wf, hob, wob, stride, activation,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc = acc_ref[...]
-    for (dh, dw), win in tap_windows(x_ref[0, 0], hf, wf, hob, wob, stride,
-                                     dilation):
-        acc = acc + jnp.dot(win, w_ref[0, 0, dh, dw],
-                            preferred_element_type=jnp.float32)
-    acc_ref[...] = acc
+    def taps(src, dtype, lead):
+        for (dh, dw), win in tap_windows(src, hf, wf, hob, wob, stride,
+                                         dilation, dtype, lead):
+            acc_ref[...] += jnp.dot(win, w_ref[0, 0, dh, dw],
+                                    preferred_element_type=jnp.float32)
+
+    strided_source(x_ref, stride, taps)
 
     # GAP guards hoisted out of the flush conditional (program_id may not be
     # issued inside a pl.when body)
@@ -127,7 +132,8 @@ def _fwd_kernel(x_ref, w_ref, *rest, hf, wf, hob, wob, stride, activation,
 
     @pl.when(last_step((4,)))
     def _flush():
-        tile = epilogue_flush(o_ref, acc, hob, wob, b_ref, activation, r_ref)
+        tile = epilogue_flush(o_ref, acc_ref[...], hob, wob, b_ref,
+                              activation, r_ref)
         # GAP rider: the spatial-tile axes (2, 3) sequence all flushes of one
         # (n, co) pair, so the f32 partial-sum scratch re-inits on the first
         # tile and the pooled pencil is written exactly once, on the last.
@@ -155,21 +161,20 @@ def _dgrad_kernel(dy_ref, *rest, hf, wf, hob, wob, has_z, activation,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    patch = dy_ref[0, 0]
+    patch, lead = dy_ref, (0, 0)
     if z_ref is not None:
-        patch = cotangent_prologue(patch, z_ref[0, 0], activation)
-    acc = acc_ref[...]
+        patch, lead = cotangent_prologue(dy_ref[0, 0], z_ref[0, 0],
+                                         activation), ()
     for (dh, dw), win in tap_windows(patch, hf, wf, hob, wob, 1,
-                                     dilation):
+                                     dilation, lead=lead):
         # [Hob*Wob, Cob] x [Cib, Cob] -> [Hob*Wob, Cib]  (contract lanes)
-        acc = acc + jax.lax.dot_general(
+        acc_ref[...] += jax.lax.dot_general(
             win, w_ref[0, 0, hf - 1 - dh, wf - 1 - dw],
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    acc_ref[...] = acc
 
     @pl.when(last_step((4,)))
     def _flush():
-        epilogue_flush(o_ref, acc, hob, wob)
+        epilogue_flush(o_ref, acc_ref[...], hob, wob)
 
 
 def _wgrad_kernel(x_ref, dy_ref, *rest, hf, wf, hob, wob, stride, has_z,
@@ -211,14 +216,18 @@ def _wgrad_kernel(x_ref, dy_ref, *rest, hf, wf, hob, wob, stride, has_z,
 
         @pl.when(last_step((1, 2, 3, 4)))
         def _db_flush():
-            db_ref[0] = dbacc_ref[0].astype(db_ref.dtype)
+            db_ref[0] = dbacc_ref[...].astype(db_ref.dtype)
 
-    for (dh, dw), win in tap_windows(x_ref[0, 0], hf, wf, hob, wob, stride,
-                                     dilation):
-        # [Hob*Wob, Cib] x [Hob*Wob, Cob] -> [Cib, Cob]  (contract positions)
-        acc_ref[dh, dw] = acc_ref[dh, dw] + jax.lax.dot_general(
-            win, dy, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def taps(src, dtype, lead):
+        for (dh, dw), win in tap_windows(src, hf, wf, hob, wob, stride,
+                                         dilation, dtype, lead):
+            # [Hob*Wob, Cib] x [Hob*Wob, Cob] -> [Cib, Cob]  (contract
+            # positions)
+            acc_ref[dh, dw] = acc_ref[dh, dw] + jax.lax.dot_general(
+                win, dy, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    strided_source(x_ref, stride, taps)
 
     @pl.when(last_step((2, 3, 4)))
     def _flush():
@@ -320,14 +329,15 @@ def _forward_windowed(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
         # block-diagonal reach into x: output block `co` belongs to group
         # co // cogblk, whose input blocks start at (co // cogblk) * cigblk.
         # groups=1 degenerates to plain `ci` — dense launches are untouched.
-        halo_window_spec(hib, wib, cib, hob * stride, wob * stride,
+        halo_window_spec(hib, wib, cib,
+                         *window_steps(ho, wo, hob, wob, stride),
                          lambda b, co, th, tw, ci:
                          (b, (co // cogblk) * cigblk + ci, th, tw)),
         weight_spec(hf, wf, cib, cob,
                     lambda b, co, th, tw, ci: (co, ci)),
     ]
     if has_bias:
-        operands.append(bias)
+        operands.append(pencils(bias))
         in_specs.append(bias_spec(cob, lambda b, co, th, tw, ci: (co,)))
     if has_residual:
         assert residual.shape == (n, coblk, ho, wo, cob), \
@@ -344,11 +354,11 @@ def _forward_windowed(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
         out_specs = [out_specs,
                      gap_spec(cob, lambda b, co, th, tw, ci: (b, co))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((n, coblk, cob), xp.dtype)]
+                     jax.ShapeDtypeStruct((n, coblk, 1, cob), xp.dtype)]
         scratch.append(pltpu.VMEM((1, cob), jnp.float32))
 
     grid = (n, coblk, ho // hob, wo // wob, cigblk)
-    return pl.pallas_call(
+    return unpencil(pl.pallas_call(
         partial(_fwd_kernel, hf=hf, wf=wf, hob=hob, wob=wob, stride=stride,
                 activation=activation, has_bias=has_bias,
                 has_residual=has_residual, has_gap=gap, hw=ho * wo,
@@ -358,8 +368,9 @@ def _forward_windowed(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=compiler_params(machine, forward_semantics(gap)),
         interpret=interpret,
-    )(*operands)
+    )(*operands), gap)
 
 
 # ---------------------------------------------------------------------------
@@ -373,8 +384,8 @@ def direct_conv2d_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
                                stride: int = 1,
                                hob: Optional[int] = None,
                                wob: Optional[int] = None,
-                               machine: MachineModel = TPU_V5E,
-                               interpret: bool = False,
+                               machine: Optional[MachineModel] = None,
+                               interpret: Optional[bool] = None,
                                stream: Optional[bool] = None,
                                hso: Optional[int] = None,
                                groups: int = 1,
@@ -410,6 +421,8 @@ def direct_conv2d_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
     shape).  The streamed route stays unfused — the prologue is applied
     outside before the ring launch.
     """
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     _inject_fault("kernel.launch")
     flag = _resolve_stream(stream, hso, "dgrad")
     dense = groups == 1 and tuple(dilation) == (1, 1)
@@ -482,7 +495,7 @@ def _dgrad_windowed(dy: jnp.ndarray, w: jnp.ndarray, stride: int,
     # cotangent blocks start at (ci // cigblk) * cogblk and the
     # matching weight block row is the same offset + the reduction id
     cot_window = lambda: halo_window_spec(
-        hib, wib, cob, hob, wob,
+        hib, wib, cob, *window_steps(eh, ew, hob, wob, 1),
         lambda b, ci, th, tw, co: (b, (ci // cigblk) * cogblk + co, th, tw))
     operands = [dyp]
     in_specs = [cot_window()]
@@ -505,6 +518,8 @@ def _dgrad_windowed(dy: jnp.ndarray, w: jnp.ndarray, stride: int,
                             lambda b, ci, th, tw, co: (b, ci, th, tw)),
         out_shape=jax.ShapeDtypeStruct((n, ciblk, eh, ew, cib), dy.dtype),
         scratch_shapes=[pltpu.VMEM((hob * wob, cib), jnp.float32)],
+        compiler_params=compiler_params(
+            machine, ("parallel",) * 4 + ("arbitrary",)),
         interpret=interpret,
     )(*operands)
 
@@ -517,8 +532,8 @@ def direct_conv2d_wgrad_pallas(xp: jnp.ndarray, dy: jnp.ndarray,
                                hf: int, wf: int, stride: int = 1,
                                hob: Optional[int] = None,
                                wob: Optional[int] = None,
-                               machine: MachineModel = TPU_V5E,
-                               interpret: bool = False,
+                               machine: Optional[MachineModel] = None,
+                               interpret: Optional[bool] = None,
                                out_dtype=None,
                                stream: Optional[bool] = None,
                                hso: Optional[int] = None,
@@ -550,6 +565,8 @@ def direct_conv2d_wgrad_pallas(xp: jnp.ndarray, dy: jnp.ndarray,
     ``db`` in f32 ``[Co/Cob, Cob]`` pencils.  The streamed route stays
     unfused: dz is formed outside and db summed by XLA.
     """
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     _inject_fault("kernel.launch")
     flag = _resolve_stream(stream, hso, "wgrad")
     dense = groups == 1 and tuple(dilation) == (1, 1)
@@ -607,7 +624,8 @@ def _wgrad_windowed(xp: jnp.ndarray, dy: jnp.ndarray, hf: int, wf: int,
 
     operands = [xp, dy]
     in_specs = [
-        halo_window_spec(hib, wib, cib, hob * stride, wob * stride,
+        halo_window_spec(hib, wib, cib,
+                         *window_steps(ho, wo, hob, wob, stride),
                          lambda co, ci, b, th, tw:
                          (b, (co // cogblk) * cigblk + ci, th, tw)),
         tile_spec(hob, wob, cob,
@@ -627,7 +645,7 @@ def _wgrad_windowed(xp: jnp.ndarray, dy: jnp.ndarray, hf: int, wf: int,
         out_specs = [out_specs,
                      bias_spec(cob, lambda co, ci, b, th, tw: (co,))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((coblk, cob), jnp.float32)]
+                     jax.ShapeDtypeStruct((coblk, 1, cob), jnp.float32)]
         scratch = [scratch[0], pltpu.VMEM((1, cob), jnp.float32)]
 
     # the weight-gradient block walk is per group: only the cigblk input
@@ -635,7 +653,7 @@ def _wgrad_windowed(xp: jnp.ndarray, dy: jnp.ndarray, hf: int, wf: int,
     # cross-group products are structural zeros of the block-diagonal weight
     # and are simply never computed)
     grid = (coblk, cigblk, n, ho // hob, wo // wob)
-    return pl.pallas_call(
+    return unpencil(pl.pallas_call(
         partial(_wgrad_kernel, hf=hf, wf=wf, hob=hob, wob=wob,
                 stride=stride, has_z=z is not None, activation=activation,
                 with_db=with_db, dilation=dilation),
@@ -644,8 +662,9 @@ def _wgrad_windowed(xp: jnp.ndarray, dy: jnp.ndarray, hf: int, wf: int,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=compiler_params(machine, wgrad_semantics(with_db)),
         interpret=interpret,
-    )(*operands)
+    )(*operands), with_db)
 
 
 # ---------------------------------------------------------------------------
@@ -805,8 +824,8 @@ def direct_conv2d_blocked_pallas(x: jnp.ndarray, w: jnp.ndarray,
                                  activation: Optional[str] = None,
                                  hob: Optional[int] = None,
                                  wob: Optional[int] = None,
-                                 machine: MachineModel = TPU_V5E,
-                                 interpret: bool = False,
+                                 machine: Optional[MachineModel] = None,
+                                 interpret: Optional[bool] = None,
                                  precision: Precision | str = F32,
                                  stream: Optional[bool] = None,
                                  hso: Optional[int] = None,
@@ -863,10 +882,18 @@ def direct_conv2d_blocked_pallas(x: jnp.ndarray, w: jnp.ndarray,
     cotangent is the map cotangent itself, and the backward kernels fuse
     ``dz = g * act'(z)`` (plus ``db``) in-kernel.
     """
+    machine = resolve_machine(machine)
+    interpret = resolve_interpret(interpret)
     n, ciblk_x, hi, wi, cib_x = x.shape
     coblk, _, hf, wf, _, cob = w.shape
-    spec = ConvSpec.make(n, hi, wi, ciblk_x * cib_x, coblk * cob, hf, wf,
+    pi, po = (lane_pad(machine, interpret, cib_x, cob) if groups == 1
+              else (0, 0))
+    spec = ConvSpec.make(n, hi, wi, ciblk_x * (cib_x + pi),
+                         coblk * (cob + po), hf, wf,
                          stride=stride, padding=padding, groups=groups,
                          dilation=dilation)
-    return _conv(x, w, bias, residual, spec, activation, hob, wob, machine,
-                 interpret, resolve_precision(precision), stream, hso, gap)
+    out = _conv(pad_lanes(x, pi), pad_lanes(pad_lanes(w, po), pi, axis=4),
+                pad_lanes(bias, po), pad_lanes(residual, po), spec,
+                activation, hob, wob, machine, interpret,
+                resolve_precision(precision), stream, hso, gap)
+    return crop_lanes(out, cob, gap, coblk)

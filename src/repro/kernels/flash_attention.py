@@ -25,6 +25,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.backend import resolve_machine
+from .conv2d_common import compiler_params
+
 __all__ = ["flash_attention_pallas"]
 
 NEG_INF = -1e30
@@ -101,5 +104,7 @@ def flash_attention_pallas(q, k, v, *, scale: float, causal: bool = True,
         scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq, dh), jnp.float32)],
+        compiler_params=compiler_params(
+            resolve_machine(None), ("parallel",) * 3 + ("arbitrary",)),
         interpret=interpret,
     )(q, k, v)

@@ -5,7 +5,7 @@ implementation through the conv dispatch subsystem — per-call ``impl``
 override, then the persistent measured table, then the analytical prior —
 over the full candidate set (window/streamed Pallas, im2col, lax, jnp
 oracle).  On TPU backends the Pallas kernels run compiled; everywhere else
-(this container: CPU) they run in ``interpret=True`` mode, which executes
+(the CPU) they run in ``interpret=True`` mode, which executes
 the same kernel body for correctness validation.  ``impl="jnp"`` pins the
 pure-JAX direct formulation in ``repro.core.direct_conv`` — same math,
 XLA-scheduled; this is also what the LM models use under ``vmap``/``scan``
@@ -15,11 +15,10 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import layout as L
-from repro.core.blocking import TPU_V5E
+from repro.core.backend import resolve_interpret, resolve_machine
 from repro.core.context import ConvContext, as_context, reject_legacy_kwargs
 from repro.core.conv_baselines import (Padding, conv_im2col, conv_lax)
 from repro.core.direct_conv import (apply_activation, bias_to_blocked,
@@ -30,12 +29,6 @@ from .conv1d_depthwise import conv1d_depthwise_blocked_pallas
 from .direct_conv2d import direct_conv2d_blocked_pallas
 
 __all__ = ["direct_conv2d", "conv1d_depthwise"]
-
-
-def _interpret_default(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def direct_conv2d(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
@@ -66,7 +59,7 @@ def direct_conv2d(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
 
     n, hi, wi, ci = x.shape
     co = w.shape[3]
-    machine = ctx.machine if ctx.machine is not None else TPU_V5E
+    machine = resolve_machine(ctx.machine)
     disp = ctx.dispatch if ctx.dispatch is not None else get_dispatcher()
     key = DispatchKey.make(n, hi, wi, ci, co, w.shape[0], w.shape[1],
                            stride, padding, ctx.precision, machine, "fwd")
@@ -97,7 +90,7 @@ def direct_conv2d(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
     bb = None if bias is None else bias_to_blocked(bias, lay.cb_out)
     yb = direct_conv2d_blocked_pallas(
         xb, wb, bb, stride=stride, padding=padding, activation=activation,
-        interpret=_interpret_default(interpret), stream=route)
+        interpret=interpret, stream=route)
     return L.blocked_to_nhwc(yb)
 
 
@@ -115,7 +108,7 @@ def conv1d_depthwise(x: jnp.ndarray, w: jnp.ndarray,
     xb = L.bld_to_blocked(x, db)
     wb = L.kd_to_blocked(w, db)
     yb = conv1d_depthwise_blocked_pallas(
-        xb, wb, lb=lb, interpret=_interpret_default(interpret))
+        xb, wb, lb=lb, interpret=resolve_interpret(interpret))
     y = L.blocked_to_bld(yb)
     if bias is not None:
         y = (y.astype(jnp.float32) + bias.astype(jnp.float32)).astype(y.dtype)

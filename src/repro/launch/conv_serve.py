@@ -15,7 +15,7 @@ Two mesh axes, two paper facts:
     reduction order as the single-device kernel, so the sharded forward is
     bit-identical — the property ``tests/test_conv_serve_tier.py`` pins.
 
-``shard_map`` (via the version-compat shim) rather than jit-with-shardings:
+``jax.shard_map`` rather than jit-with-shardings:
 the per-shard program is *exactly* the single-device program, so the Pallas
 kernel runs per shard with per-shard blocked layouts — no global-view
 resharding can be introduced behind the kernel's back, and each shard's
@@ -48,7 +48,6 @@ from repro.core.layout import nhwc_to_blocked
 from repro.nn.conv import BlockedConv2D
 from repro.serve.scheduler import (ConvRequest, Outcome, SlotPool,
                                    SpatialBucketer)
-from repro.utils.compat import shard_map
 from repro.utils.faults import inject as _inject_fault
 
 __all__ = ["make_sharded_cnn_forward", "sharded_cnn_predict",
@@ -134,8 +133,8 @@ def _make_sharded_cnn_forward(model, mesh, axis: str,
         def fwd(p, x):
             return model(p, x, context=ctx)
 
-        sharded = shard_map(fwd, mesh, in_specs=(P(), P(axis)),
-                            out_specs=P(axis))
+        sharded = jax.shard_map(fwd, mesh=mesh, in_specs=(P(), P(axis)),
+                                out_specs=P(axis), check_vma=False)
         return jax.jit(sharded)
 
     m = mesh.shape[model_axis]
@@ -157,8 +156,8 @@ def _make_sharded_cnn_forward(model, mesh, axis: str,
 
     pspecs = {f"conv{i}": P(model_axis) for i in range(len(shard_convs))}
     pspecs["head"] = P()
-    sharded = shard_map(fwd, mesh, in_specs=(pspecs, P(axis)),
-                        out_specs=P(axis))
+    sharded = jax.shard_map(fwd, mesh=mesh, in_specs=(pspecs, P(axis)),
+                            out_specs=P(axis), check_vma=False)
     return jax.jit(sharded)
 
 

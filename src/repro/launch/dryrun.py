@@ -16,7 +16,6 @@ from repro.configs.shapes import SHAPES
 from repro.launch.cells import build_cell
 from repro.launch.mesh import make_production_mesh
 from repro.nn.module import Parallelism
-from repro.utils.compat import cost_analysis_dict
 from repro.utils.hlo import collective_bytes
 
 
@@ -49,7 +48,7 @@ def refresh_unrolled(arch: str, shape_name: str, outdir: str) -> dict:
             "compile_s": round(time.time() - t0, 2),
             "cost_analysis": {
                 k: float(v) for k, v in
-                cost_analysis_dict(compiled_u).items()
+                compiled_u.cost_analysis().items()
                 if isinstance(v, (int, float))
                 and not any(ch.isdigit() for ch in k)},
             "collectives": collective_bytes(txt_u),
@@ -90,7 +89,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, outdir: str,
             compiled = lowered.compile()
             t_comp = time.time() - t_comp0
 
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis()
             ma = compiled.memory_analysis()
             txt = compiled.as_text()
             coll = collective_bytes(txt)
@@ -138,7 +137,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, outdir: str,
                     "compile_s": round(time.time() - t0u, 2),
                     "cost_analysis": {
                         k: float(v) for k, v in
-                        cost_analysis_dict(compiled_u).items()
+                        compiled_u.cost_analysis().items()
                         if isinstance(v, (int, float))
                         and not any(ch.isdigit() for ch in k)},
                     "collectives": collective_bytes(txt_u),

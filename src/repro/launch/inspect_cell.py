@@ -12,7 +12,6 @@ from repro.launch.cells import build_cell
 from repro.launch.mesh import make_production_mesh
 from repro.nn.module import Parallelism
 from repro.train.trainstep import TrainSettings
-from repro.utils.compat import cost_analysis_dict
 from repro.utils.hlo import collective_bytes, parse_shape_bytes
 
 """Hillclimb diagnosis: rebuild one cell (optionally with experimental
@@ -81,7 +80,7 @@ def main():
     t0 = time.time()
     comp = cell.lower().compile()
     print(f"compiled in {time.time() - t0:.1f}s")
-    ca = cost_analysis_dict(comp)
+    ca = comp.cost_analysis()
     ma = comp.memory_analysis()
     txt = comp.as_text()
     coll = collective_bytes(txt)

@@ -21,20 +21,10 @@ __all__ = ["make_mesh_auto", "make_production_mesh", "make_serve_mesh",
 
 
 def make_mesh_auto(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg) only exist on
-    jax >= 0.5; on older pins (0.4.x) every mesh axis is implicitly Auto, so
-    plain ``Mesh`` construction is the exact equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto (sharding propagated
+    by the compiler)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

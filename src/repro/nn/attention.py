@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..utils.compat import shard_map
 from .layers import rope, softcap
 from .module import ParamSpec, Parallelism
 
@@ -192,11 +191,11 @@ def flash_decode(q, k_new, v_new, cache: KVCache, pos, *, window, cap, scale,
             scale=scale, seq_shards=n_shards, axis="model")
         return out, ck, cv
 
-    out, ck, cv = shard_map(
+    out, ck, cv = jax.shard_map(
         inner, mesh=px.mesh,
         in_specs=(P(bs), P(bs), P(bs), P(bs, "model"), P(bs, "model"), P()),
         out_specs=(P(bs), P(bs, "model"), P(bs, "model")),
-        check=False,
+        check_vma=False,
     )(q, k_new, v_new, cache.k, cache.v, pos[None])
     return out, KVCache(ck, cv)
 

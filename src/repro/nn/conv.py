@@ -33,11 +33,10 @@ import dataclasses
 import math
 from typing import Optional, Tuple, Union
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.blocking import MachineModel, TPU_V5E, choose_blocking
+from repro.core.blocking import MachineModel, choose_blocking
 from repro.core.context import ConvContext, as_context, reject_legacy_kwargs
 from repro.core.conv_baselines import Padding, normalize_padding
 from repro.core.convspec import as_dilation
@@ -157,8 +156,10 @@ class BlockedConv2D:
                                          # f32 masters; compute casts to the
                                          # policy operand dtype at call time
                                          # (DESIGN.md §10)
-    machine: MachineModel = TPU_V5E      # VMEM budget the blocking models
-                                         # fit against (Pallas path)
+    machine: Optional[MachineModel] = None
+                                         # VMEM budget the blocking models
+                                         # fit against (Pallas path); None
+                                         # -> the running device's model
     stream: Optional[bool] = None        # Pallas kernel variant override
                                          # (DESIGN.md §11): None lets the
                                          # dispatcher resolve window-vs-
@@ -301,8 +302,6 @@ class BlockedConv2D:
                 fused_residual=residual is not None,
                 hob=self.hob, wob=self.wob, machine=machine,
                 op_bytes=pol.op_dtype.itemsize)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         return run_conv_impl(decision_impl, xb, p["w"], bias,
                              stride=self.stride, padding=self.padding,
                              activation=self.activation, precision=pol,
@@ -383,7 +382,7 @@ class DepthwiseSeparableBlock:
     dilation: Union[int, Tuple[int, int]] = 1
     lane: int = 128
     precision: Union[str, Precision] = "f32"
-    machine: MachineModel = TPU_V5E
+    machine: Optional[MachineModel] = None
 
     @property
     def depthwise(self) -> BlockedConv2D:

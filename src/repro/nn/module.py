@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
@@ -54,7 +55,9 @@ def _init_one(spec: ParamSpec, key) -> jnp.ndarray:
 
 
 def _fold_path(key, path: str):
-    return jax.random.fold_in(key, int(np.uint32(hash(path) & 0xFFFFFFFF)))
+    # crc32, not hash(): str hashes are randomized per process, so the same
+    # seed would give different weights in every run
+    return jax.random.fold_in(key, zlib.crc32(path.encode()))
 
 
 def init_tree(specs: SpecTree, key, path: str = "") -> Any:

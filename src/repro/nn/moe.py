@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
-from ..utils.compat import shard_map
 from .module import ParamSpec, Parallelism
 
 __all__ = ["MoE", "router_topk", "canonical_experts"]
@@ -260,11 +259,11 @@ class MoE:
             return y.reshape(bl, s_, d_).astype(x.dtype), aux
 
         wspec = P("model", None, None, None)
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             inner, mesh=px.mesh,
             in_specs=(P(bspec), P(None, None), wspec, wspec, wspec),
             out_specs=(P(bspec), P()),
-            check=False,
+            check_vma=False,
         )(x, p["router"]["w"], p["gate"]["w"], p["up"]["w"], p["down"]["w"])
         return y, aux
 

@@ -1,4 +1,6 @@
 """The analytical blocking model: paper Eq. 1/2 verbatim + TPU adaptation."""
+import pytest
+
 from repro.core.blocking import (CPU_HASWELL, TPU_V5E,
                                  choose_blocking, cpu_max_tile_elems,
                                  cpu_min_tile_elems, resident_bytes)
@@ -53,3 +55,64 @@ def test_overhead_table_alexnet():
     assert bytes_overhead(conv2, "mec") < im2col
     rows = overhead_table([conv2])
     assert rows[0]["im2col_vs_base"] > 1.0             # overhead exceeds base
+
+
+# ---------------------------------------------------------------------------
+# the backend decides the machine model, interpret mode and the cache
+# ---------------------------------------------------------------------------
+
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_machine_model_comes_from_device_kind():
+    from repro.core.backend import DEVICE_KINDS, machine_for_device
+    assert machine_for_device(_Device("tpu", "TPU v5 lite")) is TPU_V5E
+    assert TPU_V5E.source                # the peaks name their source
+    assert all(m.vmem_limit_bytes and m.tile for m in DEVICE_KINDS.values())
+    # off the TPU the kernels interpret the v5e launches
+    assert machine_for_device(_Device("cpu", "cpu")) is TPU_V5E
+    # an unknown TPU is an error, never a default
+    with pytest.raises(ValueError, match="no machine model"):
+        machine_for_device(_Device("tpu", "TPU v99"))
+
+
+def test_interpret_follows_the_backend(monkeypatch):
+    import jax
+    from repro.core.backend import resolve_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        resolve_interpret(True)
+
+
+def test_compile_cache_dir_from_env_or_fixed_path(monkeypatch):
+    import jax
+    from repro.utils.cache import CACHE_DIR, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/where")
+        assert enable_compile_cache() == "/some/where"
+        assert jax.config.jax_compilation_cache_dir == "/some/where"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CACHE_DIR)
+        assert CACHE_DIR.name == ".jax_cache"
+        assert (CACHE_DIR.parent / "src" / "repro").is_dir()   # the checkout
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_padded_tiles_count_what_mosaic_allocates():
+    from repro.core.blocking import tile_bytes
+    # unpadded model: plain bytes; chip model: lanes to 128, rows to 8
+    # 32-bit words (16 bf16 rows)
+    assert tile_bytes((3, 3, 3), 4) == 27 * 4
+    assert tile_bytes((3, 3, 3), 4, (8, 128)) == 3 * 8 * 128 * 4
+    assert tile_bytes((7, 128), 2, (8, 128)) == 16 * 128 * 2
+    # a narrow stem pencil costs whole lanes on the chip
+    b = choose_blocking(225, 225, 3, 32, 3, 3, 2, machine=TPU_V5E)
+    assert b.hob * b.wob <= TPU_V5E.max_tile_rows

@@ -251,9 +251,10 @@ def _layer_and_operands():
 def test_routing_source_never_changes_numerics():
     layer, p, xb = _layer_and_operands()
     y_override = layer(p, xb, context=ConvContext(impl="window"))
-    # same impl arrived at through a table entry: bitwise identical
+    # same impl arrived at through a table entry: bitwise identical (the
+    # layer's key carries its fusion tag — a ReLU layer fuses act'(z))
     key = DispatchKey.make(2, 10, 10, 4, 8, 3, 3, 1, "SAME", "f32",
-                           TPU_V5E, "fwd")
+                           TPU_V5E, "fwd", fusion="dz")
     disp = ConvDispatcher(table={key.ident: _entry(key, "window")})
     y_table = layer(p, xb, context=ConvContext(dispatch=disp))
     np.testing.assert_array_equal(np.asarray(y_override),
@@ -303,19 +304,63 @@ def test_probe_reference_impls_always_feasible():
 # the checked-in table: CI matrix coverage
 # ---------------------------------------------------------------------------
 
-def test_checked_in_table_covers_ci_matrix():
+def _benchmarks():
     repo = pathlib.Path(__file__).resolve().parent.parent
     if str(repo) not in sys.path:
         sys.path.insert(0, str(repo))
-    from benchmarks.tune_dispatch import tuned_keys
+    import benchmarks.tune_dispatch as td
+    return td
 
+
+def test_checked_in_table_covers_ci_matrix():
+    td = _benchmarks()
     disp = ConvDispatcher.from_file(missing_ok=False)
-    cover = disp.coverage(tuned_keys())
+    keys = td.tuned_keys()
+    cover = disp.coverage(keys)
     assert cover["missing"] == []
-    assert cover["prior"] == []          # the CI matrix is fully *measured*
-    assert len(cover["tuned"]) == len(tuned_keys())
-    for ident in cover["tuned"]:
-        entry = disp.table[ident]
-        assert DispatchKey.from_json(entry["key"]).ident == ident
+    # keys of the CPU test machines are measured; TPU-named keys wait for
+    # the chip (prior-seeded, "not measured")
+    for key in keys:
+        entry = disp.table[key.ident]
+        assert DispatchKey.from_json(entry["key"]).ident == key.ident
         assert Impl(entry["impl"])       # coercible
-        assert entry["times_us"]
+        if key.machine in td.TPU_MACHINES:
+            assert entry["source"] == "prior"
+        else:
+            assert entry["source"] == "tuned" and entry["times_us"]
+
+
+def test_shipped_table_has_no_cpu_timed_tpu_entry():
+    """Every time under a TPU machine name must come from the chip; none
+    has been measured there yet, so no TPU-named entry carries a time."""
+    td = _benchmarks()
+    disp = ConvDispatcher.from_file(missing_ok=False)
+    tpu = [e for e in disp.table.values()
+           if e["key"]["machine"] in td.TPU_MACHINES]
+    assert tpu
+    for entry in tpu:
+        assert entry["source"] == "prior", entry["key"]
+        assert "times_us" not in entry
+
+
+def test_tune_dispatch_refuses_tpu_times_off_tpu():
+    td = _benchmarks()
+    key = _key("fwd")                    # a tpu_v5e key
+    disp = ConvDispatcher()
+    if jax.default_backend() == "tpu":
+        pytest.skip("on a TPU the chip may time TPU-named keys")
+    assert not td.measurable(key)
+    with pytest.raises(ValueError, match="refusing to time"):
+        td.tune_key(disp, key)
+    assert key.ident not in disp.table
+    # a CPU test machine's key may be timed anywhere
+    assert td.measurable(_deep_key("fwd"))
+
+
+def test_prior_entries_are_rederived_not_replayed():
+    """A prior-seeded entry records coverage only: the decision is the
+    running backend's prior, whatever impl the seeding machine wrote."""
+    key = _key("dgrad")
+    entry = {"key": key.to_json(), "impl": "jnp", "source": "prior"}
+    dec = ConvDispatcher(table={key.ident: entry}).decide(key)
+    assert (dec.source, dec.impl) == ("prior", Impl.WINDOW)

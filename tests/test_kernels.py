@@ -113,3 +113,55 @@ def test_pallas_grid_reduction_order():
         want = ref.direct_conv2d_ref(xb, wb)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("kind", ["dense-s1", "dense-s2", "depthwise"])
+@pytest.mark.parametrize("gap", [False, True])
+def test_narrow_pencil_lane_padding_is_exact(monkeypatch, kind, gap):
+    """A compiled launch zero-pads narrow pencils to 128 lanes and crops
+    the result (``conv2d_common.lane_pad``); interpret mode pads nothing.
+    Forcing the pad here (interpret mode) gives the unpadded launch's
+    values: zero lanes in, cropped out, grads included."""
+    import importlib
+    import jax
+    from repro.core.blocking import TPU_V5E
+    from repro.kernels.conv2d_common import lane_pad
+    DC = importlib.import_module("repro.kernels.direct_conv2d")
+    DW = importlib.import_module("repro.kernels.conv2d_depthwise")
+
+    assert lane_pad(TPU_V5E, True, 3, 32) == (0, 0)
+    assert lane_pad(TPU_V5E, False, 3, 32, 128) == (125, 96, 0)
+
+    rng = np.random.default_rng(7)
+    c, stride = (8, 1) if kind == "depthwise" else (3, int(kind[-1]))
+    co = 8 if kind == "depthwise" else 4
+    x = jnp.asarray(rng.normal(size=(2, 1, 9, 9, c)), jnp.float32)
+    if kind == "depthwise":
+        w = jnp.asarray(rng.normal(size=(1, 1, 3, 3, 1, c)), jnp.float32)
+        fn, mod = DW.depthwise_conv2d_blocked_pallas, DW
+    else:
+        w = jnp.asarray(rng.normal(size=(1, 1, 3, 3, c, co)), jnp.float32)
+        fn, mod = DC.direct_conv2d_blocked_pallas, DC
+    b = jnp.asarray(rng.normal(size=(1, co)), jnp.float32)
+
+    def run(x, w, b):
+        return fn(x, w, b, stride=stride, padding="SAME", activation="relu",
+                  interpret=True, gap=gap)
+
+    def loss(x, w, b):
+        return (run(x, w, b) ** 2).sum()
+
+    want = run(x, w, b)
+    gwant = jax.grad(loss, argnums=(0, 1, 2))(x, w, b)
+    monkeypatch.setattr(mod, "lane_pad",
+                        lambda m, interp, *p: lane_pad(m, False, *p))
+    jax.clear_caches()                  # the entry points are jitted
+    got = run(x, w, b)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for g, gw in zip(jax.grad(loss, argnums=(0, 1, 2))(x, w, b), gwant):
+        assert g.shape == gw.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(gw),
+                                   rtol=1e-4, atol=1e-4)
+    jax.clear_caches()                  # drop the padded traces
