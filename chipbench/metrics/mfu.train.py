@@ -1,0 +1,12 @@
+"""Published forward and backward FLOPs per image times images per second
+of the window, over the peak of the chips, in percent.  Nothing recomputed
+or padded counts.  Moves train_step_ms."""
+from chipbench import counts
+
+
+def read(run):
+    images, elapsed = run.counters.get("images"), run.counters.get("elapsed_s")
+    if not images or not elapsed:
+        return None
+    peak = run.peak["flops_per_s"][run.cfg["precision"]] * run.cell["chips"]
+    return 100.0 * images * counts.train_flops(run.cfg) / elapsed / peak
