@@ -27,7 +27,9 @@ image sizes, a ``SpatialBucketer`` groups them onto a small set of
 dispatch-table-tuned ``(H, W)`` buckets (pad on entry, one compiled
 executable per bucket), a per-bucket ``SlotPool`` does continuous-batching
 admission, and the server reports per-request latency plus achieved batch
-occupancy (``benchmarks/bench_serve.py`` drives it under synthetic load).
+occupancy (``chipbench/serve.py`` drives it open-loop on the chip).  Each
+step also records its phases as spans in the process-wide recorder
+(``repro.utils.spans``; DESIGN.md §18).
 """
 from __future__ import annotations
 
@@ -40,7 +42,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.context import ConvContext, as_context, reject_legacy_kwargs
 from repro.core.errors import TransientError
@@ -48,6 +50,7 @@ from repro.core.layout import nhwc_to_blocked
 from repro.nn.conv import BlockedConv2D
 from repro.serve.scheduler import (ConvRequest, Outcome, SlotPool,
                                    SpatialBucketer)
+from repro.utils import spans as _spans
 from repro.utils.faults import inject as _inject_fault
 
 __all__ = ["make_sharded_cnn_forward", "sharded_cnn_predict",
@@ -240,9 +243,11 @@ class ConvServer:
     step pads the batch with zero rows rather than waiting (latency over
     occupancy; the occupancy number reports the cost of that choice).
 
-    ``clock`` is injectable: the bench passes wall time
-    (``time.monotonic``) so p50/p99 are real latencies; tests pass a
-    deterministic counter so the slot/occupancy accounting is exact.
+    ``clock`` is injectable: the chip benchmark (``chipbench/serve.py``)
+    passes ``time.perf_counter`` so latencies are real; tests pass a
+    deterministic counter so the slot/occupancy accounting is exact.  The
+    clock stamps requests and deadlines only: the spans of
+    :meth:`step` are stamped with ``time.time_ns()`` whatever it is.
 
     Fault tolerance (DESIGN.md §16) — every submitted request terminates
     in the :class:`~repro.serve.scheduler.Outcome` lattice:
@@ -267,7 +272,8 @@ class ConvServer:
         ``breaker_cooldown`` steps to re-probe.
       * **observability** — :meth:`health` snapshots queue depth, shed
         rate, outcome counters, retries, per-bucket occupancy and breaker
-        state.
+        state; :meth:`step` records each batch's phases as spans
+        (``repro.utils.spans``).
 
     ``FatalError``s (and any non-transient exception) still propagate:
     retrying a programmer error repeats it.
@@ -307,6 +313,9 @@ class ConvServer:
             "retries": 0, "transient_faults": 0, "degraded_steps": 0,
             "admit_faults": 0,
         }
+        # the forward's input sharding: each step puts its staged batch
+        # there once, so retries and the degraded executable reuse it
+        self._in_sharding = NamedSharding(mesh, P(axis))
         self._fwd = make_sharded_cnn_forward(
             model, mesh, axis, model_axis=model_axis, context=self.context)
         # the degraded executable: identical context demoted to the jnp
@@ -320,10 +329,13 @@ class ConvServer:
         """Trace + compile every bucket's executable on zero batches, so the
         first real request's latency is service time, not compile time (the
         bench calls this before starting its trace).  Warms the degraded
-        (jnp) executable too — a breaker trip must not pay a compile."""
+        (jnp) executable too — a breaker trip must not pay a compile.  The
+        zero batches go in as :meth:`step` puts its batches, on the input
+        sharding, so the executables warmed are the ones a step calls."""
         ci = self.model.convs[0].ci
         for bh, bw in self.bucketer.buckets:
-            x = np.zeros((self.batch, bh, bw, ci), np.float32)
+            x = jax.device_put(np.zeros((self.batch, bh, bw, ci), np.float32),
+                               self._in_sharding)
             jax.block_until_ready(self._fwd(self.params, x))
             jax.block_until_ready(self._fwd_jnp(self.params, x))
 
@@ -360,18 +372,18 @@ class ConvServer:
             self.completed.append(r)
 
     # -- one engine step ---------------------------------------------------
-    def _execute(self, bucket, imgs):
-        """One batched forward with the full degradation ladder: primary
-        (retry transient failures with capped backoff, breaker permitting)
-        then the bit-identical jnp executable.  Always returns logits —
-        only a ``FatalError``/foreign exception escapes."""
+    def _execute(self, bucket, x):
+        """One batched forward of the device batch ``x`` with the full
+        degradation ladder: primary (retry transient failures with capped
+        backoff, breaker permitting) then the bit-identical jnp executable.
+        Always returns logits, ready on the device — only a
+        ``FatalError``/foreign exception escapes."""
         br = self._breakers[bucket]
         if br.allow_primary(self._step_idx):
             for attempt in range(self.max_retries + 1):
                 try:
                     _inject_fault("serve.step")
-                    out = np.asarray(jax.block_until_ready(
-                        self._fwd(self.params, imgs)))
+                    out = jax.block_until_ready(self._fwd(self.params, x))
                     br.record_success()
                     return out
                 except TransientError:
@@ -383,13 +395,24 @@ class ConvServer:
                                             self.max_backoff))
             br.record_exhausted(self._step_idx)
         self._counters["degraded_steps"] += 1
-        return np.asarray(jax.block_until_ready(
-            self._fwd_jnp(self.params, imgs)))
+        return jax.block_until_ready(self._fwd_jnp(self.params, x))
 
     def step(self) -> bool:
         """One engine step: expire stale queued requests, admit into free
         slots, then run one batched forward per non-empty bucket through
-        the degradation ladder.  -> ran anything."""
+        the degradation ladder.  -> ran anything.
+
+        Each batch records a ``serve.step`` span (attrs ``step``,
+        ``bucket``, ``rows`` real and ``executed`` padded) and, with it as
+        cause, its five phases in order: ``serve.stage`` (the step's expiry
+        sweep and admission for its first batch, then drain, pad, stack and
+        zero fill), ``serve.h2d`` (the staged batch put on the forward's
+        input sharding, waited for), ``serve.forward`` (the executable up
+        to ready logits, across retries; attrs ``path`` and ``attempts``),
+        ``serve.readback`` (the logits to the host) and ``serve.complete``
+        (stamping the requests)."""
+        now, record, c = _spans.now, _spans.record, self._counters
+        t_stage = now()
         self._expire()
         try:
             self.pool.admit()
@@ -403,19 +426,41 @@ class ConvServer:
             if not reqs:
                 continue
             ran = True
+            sid = _spans.new_id()
             imgs = np.stack([self.bucketer.pad(r.image, bucket)
                              for r in reqs])
             if len(reqs) < self.batch:      # zero rows up to the executable
                 fill = np.zeros((self.batch - len(reqs),) + imgs.shape[1:],
                                 imgs.dtype)
                 imgs = np.concatenate([imgs, fill])
-            logits = self._execute(bucket, imgs)
+            t_h2d = now()
+            x = jax.block_until_ready(
+                jax.device_put(imgs, self._in_sharding))
+            faults, degraded = c["transient_faults"], c["degraded_steps"]
+            t_fwd = now()
+            out = self._execute(bucket, x)
+            t_read = now()
+            logits = np.asarray(out)
+            t_done = now()
             t = self.clock()
             for i, r in enumerate(reqs):    # batch-level exit slice
                 r.logits, r.t_done, r.done = logits[i], t, True
                 r.outcome = Outcome.OK
-                self._counters["ok"] += 1
+                c["ok"] += 1
                 self.completed.append(r)
+            t_end = now()
+            record("serve.stage", t_stage, t_h2d, 0, sid)
+            record("serve.h2d", t_h2d, t_fwd, 0, sid)
+            record("serve.forward", t_fwd, t_read, 0, sid,
+                   attrs={"path": ("degraded" if c["degraded_steps"] > degraded
+                                   else "primary"),
+                          "attempts": c["transient_faults"] - faults + 1})
+            record("serve.readback", t_read, t_done, 0, sid)
+            record("serve.complete", t_done, t_end, 0, sid)
+            record("serve.step", t_stage, t_end, sid, 0,
+                   attrs={"step": self._step_idx, "bucket": bucket,
+                          "rows": len(reqs), "executed": self.batch})
+            t_stage = now()
         self._step_idx += 1
         return ran
 
@@ -443,8 +488,9 @@ class ConvServer:
 
     def health(self) -> dict:
         """One observability snapshot: queue/outcome/fault counters plus
-        per-bucket occupancy and breaker state (the dict the bench's
-        ``faults`` section and the ops dashboard both read)."""
+        per-bucket occupancy and breaker state (read by the chip benchmark,
+        ``chip_smoke.py`` and the tests).  Cheap enough to call every step:
+        span summaries live in the recorder, not here."""
         c = dict(self._counters)
         sub = max(c["submitted"], 1)
         return {

@@ -152,8 +152,9 @@ class ConvRequest:
     ``image`` is host-side NHWC-without-N (``[H, W, C]``) of arbitrary
     spatial size; the bucketer pads it up to its bucket on admission.  The
     server stamps ``t_submit``/``t_done`` with its injected clock (tests
-    pass a deterministic counter; the bench passes ``time.monotonic``), so
-    ``latency`` is queue wait + batched service time.
+    pass a deterministic counter; the chip benchmark passes
+    ``time.perf_counter``), so ``latency`` is queue wait + batched service
+    time.
 
     ``deadline`` is absolute on the server's clock (``submit(timeout=...)``
     derives it from t_submit); a queued request past its deadline completes

@@ -11,6 +11,7 @@ from repro.configs.base import ModelConfig
 from repro.core.context import ConvContext, as_context, reject_legacy_kwargs
 from repro.nn.conv import BlockedCNN
 from repro.nn.models import EncDec
+from repro.utils import spans as _spans  # noqa: F401  (counts compiles)
 from .losses import cross_entropy
 from .optimizer import AdamW, OptState
 
