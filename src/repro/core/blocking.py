@@ -396,7 +396,8 @@ def dgrad_extents(ho: int, wo: int, hf: int, wf: int,
     """Spatial extents of the dgrad kernel's output: the input-gradient rows
     a VALID forward conv ever touched, ``E = (out - 1) * stride + filter``
     with the *effective* (dilated) filter extent (trailing rows of the
-    padded input beyond E have zero gradient)."""
+    padded input beyond E have zero gradient).  The depthwise dgrad no
+    longer uses it: it writes the unpadded input's own extents."""
     dh, dw = as_dilation(dilation)
     return ((ho - 1) * stride + (hf - 1) * dh + 1,
             (wo - 1) * stride + (wf - 1) * dw + 1)

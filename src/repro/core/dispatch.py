@@ -577,10 +577,11 @@ def probe_impl(key: DispatchKey, impl: Impl,
                 hob=hob, wob=wob, dilation=dil,
                 fused_residual=f_res, fused_gap=f_gap, **common)
         if key.direction == "dgrad":
-            # the dgrad IS the forward kernel over the stride-dilated,
-            # halo-padded cotangent at stride 1 (taps still dilated)
-            eh = (key.ho - 1) * key.stride + 1 + 2 * (key.hf - 1) * dil[0]
-            ew = (key.wo - 1) * key.stride + 1 + 2 * (key.wf - 1) * dil[1]
+            # the dgrad IS the forward kernel at stride 1 (taps still
+            # dilated) over the stride-dilated cotangent, padded to the
+            # unpadded input plus the filter reach
+            eh = key.hi + (key.hf - 1) * dil[0]
+            ew = key.wi + (key.wf - 1) * dil[1]
             return _probe(
                 choose_depthwise_blocking,
                 lambda b, kw: depthwise_resident_bytes(
@@ -1283,8 +1284,9 @@ def _tune_closure(key: DispatchKey, impl: Impl, ops: dict,
 
             def dgrad_dw(dy_, wb_):
                 return depthwise_dgrad_pallas(
-                    dy_, wb_, stride=key.stride, machine=machine,
-                    interpret=interpret, dilation=dilation)
+                    dy_, wb_, (key.hi, key.wi), stride=key.stride,
+                    pads=key.pads, machine=machine, interpret=interpret,
+                    dilation=dilation)
             return dgrad_dw, (ops["dy"], ops["wb"])
         if impl is Impl.POINTWISE:
             from repro.kernels.conv2d_pointwise import pointwise_dgrad_pallas

@@ -24,10 +24,13 @@ registers for the lifetime of one grid step:
   b block   [1, Cb]                   # when bias is given
   out block [1, 1, Hob, Wob, Cb]
 
-dgrad is the forward kernel run on the stride-dilated, ``(Hf-1)*dil``-halo-
-padded cotangent with the tap stack spatially flipped (``w[..., ::-1, ::-1,
-...]``) — exactly the transposed-conv identity, with no pencil swap because
-there is no pencil contraction to transpose.  wgrad walks ``(C/Cb, N,
+dgrad is the forward kernel run on the stride-dilated cotangent with the tap
+stack spatially flipped (``w[..., ::-1, ::-1, ...]``) — exactly the
+transposed-conv identity, with no pencil swap because there is no pencil
+contraction to transpose.  The cotangent is padded by the filter reach less
+the forward's own padding (:func:`dgrad_pads`; a negative side crops), so the
+launch writes the gradient of the *unpadded* input at its own extents, tiled
+like the forward at those extents.  wgrad walks ``(C/Cb, N,
 Ho/Hob, Wo/Wob)`` with the last three axes reduced into a resident
 ``[Hf*Wf, Cb]`` f32 scratch — the per-channel tap gradients — flushed once
 into the ``[C/Cb, 1, Hf, Wf, 1, Cb]`` weight-gradient block.
@@ -51,8 +54,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.backend import resolve_interpret, resolve_machine
 from repro.core.blocking import (MachineModel,
                                  choose_depthwise_blocking,
-                                 choose_depthwise_wgrad_blocking,
-                                 dgrad_extents)
+                                 choose_depthwise_wgrad_blocking)
 from repro.core.conv_baselines import Padding
 from repro.core.convspec import ConvSpec
 from repro.core.direct_conv import apply_activation, pad_blocked
@@ -65,7 +67,7 @@ from .conv2d_common import (bias_spec, compiler_params, cotangent_prologue,
                             tap_windows, tile_spec, unpencil, window_steps)
 
 __all__ = ["depthwise_conv2d_blocked_pallas", "depthwise_dgrad_pallas",
-           "depthwise_wgrad_pallas"]
+           "depthwise_wgrad_pallas", "dgrad_pads"]
 
 
 # ---------------------------------------------------------------------------
@@ -243,9 +245,25 @@ def _dw_forward(xp: jnp.ndarray, w: jnp.ndarray, bias, stride: int,
     )(*operands), gap)
 
 
-@partial(jax.jit, static_argnames=("stride", "hob", "wob", "machine",
-                                   "interpret", "dilation", "activation"))
-def depthwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
+def dgrad_pads(ho: int, hi: int, hf: int, stride: int = 1,
+               dilation: int = 1, pad_lo: int = 0) -> tuple[int, int]:
+    """Low/high padding, along one spatial dim, of the stride-dilated
+    cotangent (``(ho - 1) * stride + 1`` long) that the depthwise dgrad runs
+    over: ``hi + (hf - 1) * dilation`` long in all, so the stride-1 forward
+    over it yields the gradient of the forward's *unpadded* ``hi``-long input.
+    The low side is the filter reach less the forward's low pad; a negative
+    side crops cotangent rows that only ever touched the forward's padding."""
+    reach = (hf - 1) * dilation
+    lo = reach - pad_lo
+    return lo, hi + reach - lo - ((ho - 1) * stride + 1)
+
+
+@partial(jax.jit, static_argnames=("in_hw", "stride", "pads", "hob", "wob",
+                                   "machine", "interpret", "dilation",
+                                   "activation"))
+def depthwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
+                           in_hw: tuple[int, int], stride: int = 1,
+                           pads=((0, 0), (0, 0)),
                            hob: Optional[int] = None,
                            wob: Optional[int] = None,
                            machine: Optional[MachineModel] = None,
@@ -253,14 +271,19 @@ def depthwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
                            dilation=(1, 1),
                            z: Optional[jnp.ndarray] = None,
                            activation: Optional[str] = None) -> jnp.ndarray:
-    """Input gradient of the VALID blocked depthwise conv.
+    """Input gradient of the blocked depthwise conv.
+
+    dy: [N, C/Cb, Ho, Wo, Cb] cotangent of a forward over the ``in_hw =
+    (Hi, Wi)`` input padded by ``pads = ((ph_lo, ph_hi), (pw_lo, pw_hi))``
+    -> [N, C/Cb, Hi, Wi, Cb], the gradient of the *unpadded* input.
 
     The transposed depthwise conv is itself a depthwise conv: stride-dilate
-    the cotangent, halo-pad by the effective filter reach, flip the tap
-    stack spatially, and run the forward kernel at stride 1 (forward filter
-    dilation still strides the taps).  Returns the gradient w.r.t. the
-    padded input, truncated at the touched extents
-    (``blocking.dgrad_extents``).
+    the cotangent, pad it by the effective filter reach less the forward's
+    own padding (:func:`dgrad_pads` — the forward's pads, transposed), flip
+    the tap stack spatially, and run the forward kernel at stride 1 (forward
+    filter dilation still strides the taps).  Each element sums the same
+    taps in the same order as a full-halo dgrad cropped afterwards, so the
+    result is bit-identical to it, without the crop pass or the halo rows.
 
     ``z``/``activation`` fuse the activation prologue: ``z`` is the saved
     pre-activation map (same shape as ``dy``), dilated and padded alongside
@@ -268,19 +291,17 @@ def depthwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
     the dilation zeros stay zero because the prologue is elementwise."""
     machine = resolve_machine(machine)
     interpret = resolve_interpret(interpret)
-    n, cblk, ho, wo, cb = dy.shape
-    _, _, hf, wf, _, _ = w.shape
-    dil_h, dil_w = dilation
+    ho, wo = dy.shape[2:4]
+    hf, wf = w.shape[2:4]
+    (ph_lo, _), (pw_lo, _) = pads
+    edges = (dgrad_pads(ho, in_hw[0], hf, stride, dilation[0], ph_lo),
+             dgrad_pads(wo, in_hw[1], wf, stride, dilation[1], pw_lo))
+    config = ((0, 0, 0), (0, 0, 0),
+              *((lo, hi, stride - 1) for lo, hi in edges), (0, 0, 0))
 
     def _dilate_pad(t):
-        if stride > 1:
-            td = jnp.zeros((n, cblk, (ho - 1) * stride + 1,
-                            (wo - 1) * stride + 1, cb), t.dtype)
-            td = td.at[:, :, ::stride, ::stride, :].set(t)
-        else:
-            td = t
-        return pad_blocked(td, ((hf - 1) * dil_h, (hf - 1) * dil_h),
-                           ((wf - 1) * dil_w, (wf - 1) * dil_w))
+        # interior padding stride-dilates; negative edges crop
+        return jax.lax.pad(t, jnp.zeros((), t.dtype), config)
 
     dyp = _dilate_pad(dy)
     zp = None if z is None else _dilate_pad(z)
@@ -433,16 +454,11 @@ def _dwconv_bwd(spec, activation, hob, wob, machine, interpret, precision,
     dres = None if r_token is None else g.astype(r_token.dtype)
     zs = None if z is None else z.astype(g.dtype)
 
-    (ph_lo, ph_hi), (pw_lo, pw_hi) = spec.pads
-    hi_p, wi_p = xp.shape[2], xp.shape[3]
-    hi, wi = hi_p - ph_lo - ph_hi, wi_p - pw_lo - pw_hi
-    dxp = depthwise_dgrad_pallas(g, wq, stride=stride, machine=machine,
-                                 interpret=interpret, dilation=dilation,
-                                 z=zs, activation=activation)
-    eh, ew = dxp.shape[2], dxp.shape[3]
-    dxp = jnp.pad(dxp, ((0, 0), (0, 0), (0, hi_p - eh), (0, wi_p - ew),
-                        (0, 0)))
-    dx = dxp[:, :, ph_lo:ph_lo + hi, pw_lo:pw_lo + wi, :].astype(x_token.dtype)
+    dx = depthwise_dgrad_pallas(g, wq, (spec.hi, spec.wi), stride=stride,
+                                pads=spec.pads, machine=machine,
+                                interpret=interpret, dilation=dilation,
+                                z=zs, activation=activation
+                                ).astype(x_token.dtype)
 
     if bias is not None:
         dw, db32 = depthwise_wgrad_pallas(

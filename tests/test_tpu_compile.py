@@ -84,7 +84,8 @@ def test_window_wgrad_compiles(one_chip, precision):
 
 
 @pytest.mark.parametrize("precision", DTYPES)
-@pytest.mark.parametrize("hw,c,stride", [(112, 64, 2), (14, 512, 1)])
+@pytest.mark.parametrize("hw,c,stride", [(112, 32, 1), (112, 64, 2),
+                                         (14, 512, 1)])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_depthwise_compiles(one_chip, direction, hw, c, stride, precision):
     cb = min(c, 128)
