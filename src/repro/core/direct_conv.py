@@ -35,13 +35,60 @@ __all__ = [
     "direct_conv_blocked", "direct_conv_nhwc", "direct_conv1d_depthwise",
 ]
 
+# erf(x) = x * P(x^2) / Q(x^2) on [-4, 4] (erf(4) is 1 in f32): the
+# rational approximation XLA and Eigen use for f32 erf, good to a few ulps.
+# Mosaic lowers no erf, so the kernels' epilogue spells it out in
+# multiplies, adds and one divide.
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04,
+          -2.95459980854025e-03, -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+_SQRT_HALF = 0.7071067811865476
+_INV_SQRT_2PI = 0.3989422804014327
+
+
+def _horner(coeffs, t):
+    acc = jnp.full_like(t, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * t + c
+    return acc
+
+
+def erf_f32(x: jnp.ndarray) -> jnp.ndarray:
+    """erf in f32 from elementary ops (see ``_ERF_P``)."""
+    x = jnp.clip(x, -4.0, 4.0)
+    t = x * x
+    return x * _horner(_ERF_P, t) / _horner(_ERF_Q, t)
+
+
+@jax.custom_jvp
+def gelu_erf(x: jnp.ndarray) -> jnp.ndarray:
+    """Exact GELU, ``x * Phi(x)`` with the erf form of the normal CDF
+    (PyTorch's ``nn.GELU()``, ``jax.nn.gelu(approximate=False)``); its
+    derivative ``Phi(x) + x * phi(x)`` is written out, not taken through
+    the erf polynomial."""
+    return 0.5 * x * (1.0 + erf_f32(x * _SQRT_HALF))
+
+
+@gelu_erf.defjvp
+def _gelu_erf_jvp(primals, tangents):
+    (x,), (t,) = primals, tangents
+    cdf = 0.5 * (1.0 + erf_f32(x * _SQRT_HALF))
+    pdf = jnp.exp(-0.5 * x * x) * _INV_SQRT_2PI
+    return x * cdf, t * (cdf + x * pdf)
+
+
 # Epilogue activations fused into the conv (both the jnp oracle and the
-# Pallas kernel body call this on the f32 accumulator).
+# Pallas kernel body call this on the f32 accumulator).  "gelu" is the tanh
+# approximation; "gelu_erf" the exact form.
 _ACTIVATIONS = {
     None: lambda x: x,
     "linear": lambda x: x,
     "relu": lambda x: jnp.maximum(x, 0.0),
     "gelu": jax.nn.gelu,
+    "gelu_erf": gelu_erf,
 }
 
 

@@ -44,13 +44,15 @@ from repro.core.direct_conv import direct_conv_blocked
 from repro.core.dispatch import (ConvDispatcher, DispatchKey, Impl,
                                  KernelRoute, PALLAS_IMPLS, get_dispatcher,
                                  run_conv_impl)
-from repro.core.layout import BlockedConvLayout, nhwc_to_blocked
+from repro.core.layout import BlockedConvLayout, choose_pencil, nhwc_to_blocked
 from repro.core.precision import Precision, resolve_precision
 from repro.kernels.conv2d_common import tree_sum
+from repro.kernels.layer_norm import layer_norm_blocked, layer_norm_blocked_ref
 from .module import ParamSpec
 
 __all__ = ["BlockedConv2D", "ResidualBlock", "DepthwiseSeparableBlock",
-           "BlockedCNN", "blocked_global_avg_pool"]
+           "BlockedLayerNorm", "ConvNeXtBlock", "PatchifyStem",
+           "Downsample", "BlockedCNN", "blocked_global_avg_pool"]
 
 
 def blocked_global_avg_pool(xb: jnp.ndarray,
@@ -424,18 +426,228 @@ class DepthwiseSeparableBlock:
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockedLayerNorm:
+    """LayerNorm over the channels of each pixel, on the blocked layout.
+
+    ``[N, C/Cb, H, W, Cb]`` in and out, no NHWC round trip: statistics in
+    f32 over all C channels of a pixel (every pencil block, every lane),
+    biased variance, ``eps`` inside the root, an f32 affine ``g``/``b`` per
+    channel stored as pencils ``[C/Cb, Cb]``; the output is the policy's
+    operand dtype.  The Pallas kernels (``kernels/layer_norm``) run unless
+    the context forces the jnp oracle (``impl="jnp"``).  Flat ``[N, C]``
+    features (the pooled head) are a map of one pixel.
+    """
+
+    c: int
+    eps: float = 1e-6
+    lane: int = 128
+    precision: Union[str, Precision] = "f32"
+    machine: Optional[MachineModel] = None
+
+    @property
+    def out_pencil(self) -> int:
+        return choose_pencil(self.c, self.lane)
+
+    def specs(self):
+        shape = (self.c // self.out_pencil, self.out_pencil)
+        return {"g": ParamSpec(shape, (None, None), init="ones"),
+                "b": ParamSpec(shape, (None, None), init="zeros")}
+
+    def __call__(self, p, xb: jnp.ndarray, *,
+                 context: Optional[ConvContext] = None) -> jnp.ndarray:
+        ctx = as_context(context)
+        pol = ctx.resolve_precision_for(self.precision)
+        flat = xb.ndim == 2
+        if flat:
+            n = xb.shape[0]
+            cb = self.out_pencil
+            xb = xb.reshape(n, self.c // cb, 1, 1, cb)
+        if ctx.impl is not None and Impl(ctx.impl) is Impl.JNP:
+            y = layer_norm_blocked_ref(xb.astype(pol.op_dtype), p["g"],
+                                       p["b"], eps=self.eps)
+        else:
+            y = layer_norm_blocked(
+                xb, p["g"], p["b"], eps=self.eps, precision=pol,
+                machine=ctx.resolve_machine_for(self.machine),
+                interpret=ctx.interpret)
+        return y.reshape(n, self.c) if flat else y
+
+
+def _linear_1x1(ci: int, co: int, activation, lane, precision, machine):
+    return BlockedConv2D(ci=ci, co=co, hf=1, wf=1, stride=1,
+                         padding="VALID", activation=activation, lane=lane,
+                         precision=precision, machine=machine)
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvNeXtBlock:
+    """The ConvNeXt block (Liu et al. 2022, Fig. 4), on the blocked layout:
+
+        x -> dw kxk + bias -> LN -> pw C->eC + bias, GELU (erf)
+          -> pw eC->C + bias -> * gamma -> + x
+
+    The layer scale ``gamma`` (a per-channel parameter) is folded into the
+    second pointwise conv at call time, ``gamma (W h + b) = (W diag gamma) h
+    + gamma b``, which autodiff differentiates exactly; the skip add rides
+    that conv's fused epilogue, and with ``gap`` so does the pool, of the
+    sum.  No activation anywhere but the GELU.
+    """
+
+    c: int
+    k: int = 7
+    expansion: int = 4
+    eps: float = 1e-6
+    lane: int = 128
+    precision: Union[str, Precision] = "f32"
+    machine: Optional[MachineModel] = None
+
+    @property
+    def depthwise(self) -> BlockedConv2D:
+        return BlockedConv2D(ci=self.c, co=self.c, hf=self.k, wf=self.k,
+                             stride=1, padding="SAME", activation=None,
+                             groups=self.c, lane=self.lane,
+                             precision=self.precision, machine=self.machine)
+
+    @property
+    def norm(self) -> BlockedLayerNorm:
+        return BlockedLayerNorm(c=self.c, eps=self.eps, lane=self.lane,
+                                precision=self.precision,
+                                machine=self.machine)
+
+    @property
+    def pw1(self) -> BlockedConv2D:
+        return _linear_1x1(self.c, self.expansion * self.c, "gelu_erf",
+                           self.lane, self.precision, self.machine)
+
+    @property
+    def pw2(self) -> BlockedConv2D:
+        return _linear_1x1(self.expansion * self.c, self.c, None, self.lane,
+                           self.precision, self.machine)
+
+    @property
+    def ci(self) -> int:
+        return self.c
+
+    co = ci
+
+    @property
+    def in_pencil(self) -> int:
+        return self.depthwise.in_pencil
+
+    @property
+    def out_pencil(self) -> int:
+        return self.pw2.out_pencil
+
+    def specs(self):
+        cb = self.pw2.out_pencil
+        return {"dw": self.depthwise.specs(), "norm": self.norm.specs(),
+                "pw1": self.pw1.specs(), "pw2": self.pw2.specs(),
+                "gamma": ParamSpec((self.c // cb, cb), (None, None),
+                                   init="ones")}
+
+    def __call__(self, p, xb: jnp.ndarray, *,
+                 context: Optional[ConvContext] = None,
+                 gap: bool = False) -> jnp.ndarray:
+        ctx = as_context(context)
+        h = self.depthwise(p["dw"], xb, context=ctx)
+        h = self.norm(p["norm"], h, context=ctx)
+        h = self.pw1(p["pw1"], h, context=ctx)
+        g = p["gamma"]                                   # [C/Cb, Cb]
+        pw2 = {"w": p["pw2"]["w"] * g[:, None, None, None, None, :],
+               "b": p["pw2"]["b"] * g}
+        return self.pw2(pw2, h, context=ctx, residual=xb, gap=gap)
+
+
+@dataclasses.dataclass(frozen=True)
+class _PatchConv:
+    """A ``k x k`` stride-``k`` VALID conv with bias (non-overlapping
+    patches) and a LayerNorm: ConvNeXt's stem and downsampling layers."""
+
+    ci: int
+    co: int
+    k: int = 2
+    eps: float = 1e-6
+    lane: int = 128
+    precision: Union[str, Precision] = "f32"
+    machine: Optional[MachineModel] = None
+
+    @property
+    def conv(self) -> BlockedConv2D:
+        return BlockedConv2D(ci=self.ci, co=self.co, hf=self.k, wf=self.k,
+                             stride=self.k, padding="VALID", activation=None,
+                             lane=self.lane, precision=self.precision,
+                             machine=self.machine)
+
+    def _norm(self, c: int) -> BlockedLayerNorm:
+        return BlockedLayerNorm(c=c, eps=self.eps, lane=self.lane,
+                                precision=self.precision,
+                                machine=self.machine)
+
+    @property
+    def in_pencil(self) -> int:
+        return self.conv.in_pencil
+
+    @property
+    def out_pencil(self) -> int:
+        return self.conv.out_pencil
+
+    def specs(self):
+        return {"conv": self.conv.specs(), "norm": self.norm.specs()}
+
+
+@dataclasses.dataclass(frozen=True)
+class PatchifyStem(_PatchConv):
+    """ConvNeXt's stem: the patch conv (4x4 stride 4), then LN over its
+    output channels."""
+
+    k: int = 4
+
+    @property
+    def norm(self) -> BlockedLayerNorm:
+        return self._norm(self.co)
+
+    def __call__(self, p, xb, *, context: Optional[ConvContext] = None,
+                 gap: bool = False):
+        if gap:
+            raise ValueError("the stem cannot be the last layer")
+        h = self.conv(p["conv"], xb, context=context)
+        return self.norm(p["norm"], h, context=context)
+
+
+@dataclasses.dataclass(frozen=True)
+class Downsample(_PatchConv):
+    """ConvNeXt's downsampling layer: LN over the input channels, then the
+    patch conv (2x2 stride 2) from ``ci`` to ``co`` channels."""
+
+    @property
+    def norm(self) -> BlockedLayerNorm:
+        return self._norm(self.ci)
+
+    def __call__(self, p, xb, *, context: Optional[ConvContext] = None,
+                 gap: bool = False):
+        h = self.norm(p["norm"], xb, context=context)
+        return self.conv(p["conv"], h, context=context, gap=gap)
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockedCNN:
     """conv -> ... -> conv -> GAP -> linear head, chained in blocked layout.
 
     NHWC images are blocked exactly once at entry; every layer boundary after
     that stays in ``[N, C/Cb, H, W, Cb]`` — zero pack/unpack traffic between
     layers (``benchmarks/cnn_zoo.py`` accounts the eliminated bytes).  Layers
-    are anything with the blocked-conv calling convention: ``BlockedConv2D``
-    or ``DepthwiseSeparableBlock`` mix freely.
+    are anything with the blocked-conv calling convention: ``BlockedConv2D``,
+    ``DepthwiseSeparableBlock`` and the ConvNeXt layers mix freely.
+
+    ``head_norm`` is a LayerNorm of the pooled features before the head
+    (ConvNeXt's), part of the model with its own parameters; ``head_bias``
+    gives the head a bias.
     """
 
     convs: Tuple[BlockedConv2D, ...]
     n_classes: int
+    head_norm: Optional[BlockedLayerNorm] = None
+    head_bias: bool = False
 
     def __post_init__(self):
         for a, b in zip(self.convs, self.convs[1:]):
@@ -448,8 +660,13 @@ class BlockedCNN:
 
     def specs(self):
         s = {f"conv{i}": c.specs() for i, c in enumerate(self.convs)}
+        if self.head_norm is not None:
+            s["head_norm"] = self.head_norm.specs()
         s["head"] = ParamSpec((self.convs[-1].co, self.n_classes),
                               (None, None))
+        if self.head_bias:
+            s["head_bias"] = ParamSpec((self.n_classes,), (None,),
+                                       init="zeros")
         return s
 
     def __call__(self, p, x_nhwc: jnp.ndarray, *,
@@ -479,4 +696,9 @@ class BlockedCNN:
         for i, conv in enumerate(self.convs):
             h = conv(p[f"conv{i}"], h, context=ctx, gap=(i == last))
         feat = h                      # [N, C] — pooled in the conv epilogue
-        return feat @ p["head"].astype(feat.dtype)
+        if self.head_norm is not None:
+            feat = self.head_norm(p["head_norm"], feat, context=ctx)
+        logits = feat @ p["head"].astype(feat.dtype)
+        if self.head_bias:
+            logits = logits + p["head_bias"].astype(logits.dtype)
+        return logits

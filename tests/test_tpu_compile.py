@@ -1,6 +1,7 @@
 """The main-path kernels compile for a TPU v5e.
 
-Each test lowers one launch at a MobileNet-v1 width and compiles it for a
+Each test lowers one launch at a MobileNet-v1 or ConvNeXt-T width and
+compiles it for a
 described (not attached) ``v5e:2x2`` topology with the installed TPU
 compiler — what Mosaic would refuse on the chip (unaligned windows, strided
 slices, too much VMEM) it refuses here, at no chip time.  Nothing runs, so
@@ -23,6 +24,7 @@ from repro.kernels.conv2d_pointwise import pointwise_conv2d_blocked_pallas
 from repro.kernels.direct_conv2d import (direct_conv2d_blocked_pallas,
                                          direct_conv2d_dgrad_pallas,
                                          direct_conv2d_wgrad_pallas)
+from repro.kernels.layer_norm import layer_norm_blocked
 
 DTYPES = ("f32", "bf16")
 
@@ -122,3 +124,42 @@ def test_stream_forward_compiles(one_chip, precision):
         x, w, b, stride=1, padding="SAME", activation="relu",
         precision=precision, stream=True, **KW),
         (2, 1, 30, 30, 128), (1, 1, 3, 3, 128, 128), (1, 128))
+
+
+# ConvNeXt-T: the channel LayerNorm (96-channel pencils padded for the
+# launch, and 3 pencils of 128), the 7x7 depthwise conv, and the kernel ==
+# stride convs of the stem and the downsampling layers with their dgrad
+
+
+@pytest.mark.parametrize("k,hw,cb", [(1, 56, 96), (2, 28, 96), (3, 14, 128)])
+def test_layer_norm_compiles(one_chip, k, hw, cb):
+    def loss(x, g, b):
+        y = layer_norm_blocked(x, g, b, precision="bf16", **KW)
+        return y.astype(jnp.float32).sum()
+
+    text = _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+                    (2, k, hw, hw, cb), (k, cb), (k, cb))
+    assert "layer_norm_bwd_pallas" in text
+
+
+def test_depthwise_7x7_compiles(one_chip):
+    def fwd(x, w, b):
+        return depthwise_conv2d_blocked_pallas(
+            x, w, b, stride=1, padding="SAME", activation=None,
+            precision="bf16", **KW)
+
+    _compile(one_chip, jax.grad(
+        lambda x, w, b: fwd(x, w, b).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), (2, 1, 56, 56, 96), (1, 1, 7, 7, 1, 96), (1, 96))
+
+
+@pytest.mark.parametrize("k,hw,ci,co", [(4, 224, 3, 96), (2, 56, 96, 96)])
+def test_kernel_equals_stride_conv_compiles(one_chip, k, hw, ci, co):
+    def fwd(x, w, b):
+        return direct_conv2d_blocked_pallas(
+            x, w, b, stride=k, padding="VALID", activation=None,
+            precision="bf16", stream=False, **KW)
+
+    _compile(one_chip, jax.grad(
+        lambda x, w, b: fwd(x, w, b).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), (2, 1, hw, hw, ci), (1, 1, k, k, ci, co), (1, co))
