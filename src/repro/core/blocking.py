@@ -41,6 +41,7 @@ __all__ = [
     "depthwise_resident_bytes", "choose_depthwise_blocking",
     "depthwise_wgrad_resident_bytes", "choose_depthwise_wgrad_blocking",
     "pointwise_resident_bytes", "choose_pointwise_blocking",
+    "pointwise_dgrad_resident_bytes", "choose_pointwise_dgrad_blocking",
     "pointwise_wgrad_resident_bytes", "choose_pointwise_wgrad_blocking",
 ]
 
@@ -973,13 +974,10 @@ def pointwise_resident_bytes(hob: int, wob: int, cob: int, cib: int,
                              in_dtype_bytes: int = 4,
                              acc_dtype_bytes: int = 4,
                              fused_residual: bool = False,
-                             fused_gap: bool = False,
-                             fused_prologue: bool = False, tile=None) -> int:
+                             fused_gap: bool = False, tile=None) -> int:
     """VMEM bytes one pointwise grid step holds resident: double-buffered
     input tile, [Cib, Cob] weight matrix and output tile, plus the f32
-    accumulator.  Fused riders follow :func:`resident_bytes`; for the dgrad
-    flavor ``fused_prologue`` adds the ``z`` tile pipelined next to the
-    incoming cotangent."""
+    accumulator.  Fused riders follow :func:`resident_bytes`."""
     xin = tile_bytes((hob, wob, cib), in_dtype_bytes, tile)
     wgt = tile_bytes((cib, cob), in_dtype_bytes, tile)
     out = tile_bytes((hob, wob, cob), in_dtype_bytes, tile)
@@ -990,8 +988,6 @@ def pointwise_resident_bytes(hob: int, wob: int, cob: int, cib: int,
     if fused_gap:
         total += (2 * tile_bytes((1, cob), in_dtype_bytes, tile)
                   + tile_bytes((1, cob), acc_dtype_bytes, tile))
-    if fused_prologue:
-        total += 2 * xin
     return total
 
 
@@ -1003,13 +999,12 @@ def choose_pointwise_blocking(
     hob: int | None = None, wob: int | None = None,
     precision=None,
     fused_residual: bool = False, fused_gap: bool = False,
-    fused_prologue: bool = False,
 ) -> Blocking:
-    """Tile the 1x1-as-matmul kernel (forward, and dgrad with the channel
-    pencils swapped by the caller).  Output extents equal input extents
-    (stride 1, no pads — the pointwise feasibility gate); shrink order is
-    ``hob`` -> ``wob`` -> ``cib``, the window model's order minus the halo
-    terms that no longer exist."""
+    """Tile the 1x1-as-matmul kernel (forward, and the prologue-free dgrad
+    with the channel pencils swapped by the caller).  Output extents equal
+    input extents (stride 1, no pads — the pointwise feasibility gate);
+    shrink order is ``hob`` -> ``wob`` -> ``cib``, the window model's order
+    minus the halo terms that no longer exist."""
     in_dtype_bytes, acc_dtype_bytes = _policy_itemsizes(
         precision, in_dtype_bytes, acc_dtype_bytes)
     ho, wo = hi, wi
@@ -1034,7 +1029,6 @@ def choose_pointwise_blocking(
                 hob_, wob_, cob, cib_, in_dtype_bytes,
                 acc_dtype_bytes, fused_residual=fused_residual,
                 fused_gap=fused_gap,
-                fused_prologue=fused_prologue,
                 tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
                     machine, hob_ * wob_)
 
@@ -1049,22 +1043,88 @@ def choose_pointwise_blocking(
     return Blocking(cob=cob, cib=cib, hob=hob, wob=wob)
 
 
+def _pinned_rows(ho: int, wo: int, hob, wob) -> tuple[int, int, bool, bool]:
+    """Validate pinned spatial tiles of a backward pointwise launch; ->
+    ``(hob, wob, hob_pinned, wob_pinned)``, unpinned ones the whole map."""
+    if ho <= 0 or wo <= 0:
+        raise ValueError(f"empty cotangent {ho}x{wo}")
+    hob_pinned, wob_pinned = hob is not None, wob is not None
+    if hob_pinned and (hob < 1 or ho % hob):
+        raise ValueError(f"hob={hob} must divide Ho={ho}")
+    if wob_pinned and (wob < 1 or wo % wob):
+        raise ValueError(f"wob={wob} must divide Wo={wo}")
+    return (hob if hob_pinned else ho, wob if wob_pinned else wo,
+            hob_pinned, wob_pinned)
+
+
+def _shrink_rows(ho: int, wo: int, hob: int, wob: int, hob_pinned: bool,
+                 wob_pinned: bool, fits, what: str, machine: MachineModel):
+    """Shrink ``hob`` then ``wob`` until ``fits(hob, wob)``; raise
+    :class:`VmemMisfitError` when even the smallest tile does not fit."""
+    hob = _shrink_to_fit(ho, hob, hob_pinned, lambda h: fits(h, wob))
+    wob = _shrink_to_fit(wo, wob, wob_pinned, lambda w: fits(hob, w))
+    if not fits(hob, wob):
+        raise VmemMisfitError(
+            f"{what} tile does not fit VMEM at hob={hob}, wob={wob}: needs "
+            f"more than {machine.vmem_bytes} bytes resident")
+    return hob, wob
+
+
+def pointwise_dgrad_resident_bytes(hob: int, wob: int, cob: int, cib: int,
+                                   ciblk: int, in_dtype_bytes: int = 4,
+                                   acc_dtype_bytes: int = 4,
+                                   tile=None) -> int:
+    """Residency of the prologue dgrad's grid step, which takes every
+    input-channel block: the cotangent and ``z`` tiles and the ``dz`` tile
+    it writes back, the ``[ciblk, Cib, Cob]`` weight block and the
+    ``[ciblk, hob, wob, Cib]`` dx block (all double-buffered), and one f32
+    ``[hob*wob, Cib]`` accumulator per input-channel block."""
+    cot = tile_bytes((hob, wob, cob), in_dtype_bytes, tile)
+    wgt = tile_bytes((ciblk, cib, cob), in_dtype_bytes, tile)
+    dx = tile_bytes((ciblk, hob, wob, cib), in_dtype_bytes, tile)
+    acc = tile_bytes((ciblk, hob * wob, cib), acc_dtype_bytes, tile)
+    return 2 * (3 * cot + wgt + dx) + acc
+
+
+def choose_pointwise_dgrad_blocking(
+    ho: int, wo: int, ciblk: int, machine: MachineModel = TPU_V5E,
+    cob: int = 128, cib: int = 128,
+    in_dtype_bytes: int = 4, acc_dtype_bytes: int = 4,
+    hob: int | None = None, wob: int | None = None,
+    precision=None,
+) -> Blocking:
+    """Tile the prologue dgrad: pencils pinned by the operand layouts, all
+    ``ciblk`` input-channel blocks in one grid step.  The spatial tile
+    shrinks ``hob`` -> ``wob`` until the step fits VMEM and its
+    ``ciblk * hob * wob`` accumulator rows (the matmul rows Mosaic unrolls
+    the body over) stay under the machine's row cap."""
+    in_dtype_bytes, acc_dtype_bytes = _policy_itemsizes(
+        precision, in_dtype_bytes, acc_dtype_bytes)
+    hob, wob, hob_pinned, wob_pinned = _pinned_rows(ho, wo, hob, wob)
+    if machine.vmem_bytes:
+        def fits(hob_, wob_):
+            return pointwise_dgrad_resident_bytes(
+                hob_, wob_, cob, cib, ciblk, in_dtype_bytes,
+                acc_dtype_bytes, tile=machine.tile) <= machine.vmem_bytes \
+                and _rows_ok(machine, ciblk * hob_ * wob_)
+
+        hob, wob = _shrink_rows(ho, wo, hob, wob, hob_pinned, wob_pinned,
+                                fits, "pointwise dgrad", machine)
+    return Blocking(cob=cob, cib=cib, hob=hob, wob=wob)
+
+
 def pointwise_wgrad_resident_bytes(hob: int, wob: int, cob: int, cib: int,
                                    in_dtype_bytes: int = 4,
                                    acc_dtype_bytes: int = 4,
-                                   fused_prologue: bool = False,
                                    fused_bias: bool = False, tile=None) -> int:
     """Pointwise wgrad residency: x tile, cotangent tile, and the [Cib, Cob]
-    weight-gradient block + matching f32 accumulator.  ``fused_prologue``
-    adds the saved ``z`` tile, ``fused_bias`` the [1, Cob] db block plus
-    its f32 scratch."""
+    weight-gradient block + matching f32 accumulator.  ``fused_bias`` adds
+    the [1, Cob] db block plus its f32 scratch."""
     xin = tile_bytes((hob, wob, cib), in_dtype_bytes, tile)
     cot = tile_bytes((hob, wob, cob), in_dtype_bytes, tile)
     wgt = tile_bytes((cib, cob), in_dtype_bytes, tile)
     acc = tile_bytes((cib, cob), acc_dtype_bytes, tile)
     total = 2 * (xin + cot + wgt) + acc
-    if fused_prologue:
-        total += 2 * cot
     if fused_bias:
         total += 3 * tile_bytes((1, cob), acc_dtype_bytes, tile)
     return total
@@ -1075,40 +1135,22 @@ def choose_pointwise_wgrad_blocking(
     cob: int = 128, cib: int = 128,
     in_dtype_bytes: int = 4, acc_dtype_bytes: int = 4,
     hob: int | None = None, wob: int | None = None,
-    precision=None,
-    fused_prologue: bool = False, fused_bias: bool = False,
+    precision=None, fused_bias: bool = False,
 ) -> Blocking:
     """Tile the pointwise wgrad kernel: pencils pinned by the operand
     layouts (the [Cib, Cob] accumulator is the output block), spatial tile
     shrinks ``hob`` -> ``wob`` under pressure."""
     in_dtype_bytes, acc_dtype_bytes = _policy_itemsizes(
         precision, in_dtype_bytes, acc_dtype_bytes)
-    if ho <= 0 or wo <= 0:
-        raise ValueError(f"empty cotangent {ho}x{wo}")
-    hob_pinned, wob_pinned = hob is not None, wob is not None
-    if hob_pinned and (hob < 1 or ho % hob):
-        raise ValueError(f"hob={hob} must divide Ho={ho}")
-    if wob_pinned and (wob < 1 or wo % wob):
-        raise ValueError(f"wob={wob} must divide Wo={wo}")
-    if not hob_pinned:
-        hob = ho
-    if not wob_pinned:
-        wob = wo
-
+    hob, wob, hob_pinned, wob_pinned = _pinned_rows(ho, wo, hob, wob)
     if machine.vmem_bytes:
         def fits(hob_, wob_):
             return pointwise_wgrad_resident_bytes(
-                hob_, wob_, cob, cib, in_dtype_bytes,
-                acc_dtype_bytes, fused_prologue=fused_prologue,
+                hob_, wob_, cob, cib, in_dtype_bytes, acc_dtype_bytes,
                 fused_bias=fused_bias,
                 tile=machine.tile) <= machine.vmem_bytes and _rows_ok(
                     machine, hob_ * wob_)
 
-        hob = _shrink_to_fit(ho, hob, hob_pinned, lambda h: fits(h, wob))
-        wob = _shrink_to_fit(wo, wob, wob_pinned, lambda w: fits(hob, w))
-        if not fits(hob, wob):
-            raise VmemMisfitError(
-                f"pointwise wgrad tile does not fit VMEM at hob={hob}, "
-                f"wob={wob}: needs more than {machine.vmem_bytes} bytes "
-                f"resident")
+        hob, wob = _shrink_rows(ho, wo, hob, wob, hob_pinned, wob_pinned,
+                                fits, "pointwise wgrad", machine)
     return Blocking(cob=cob, cib=cib, hob=hob, wob=wob)

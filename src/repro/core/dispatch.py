@@ -77,11 +77,13 @@ from .blocking import (MachineModel, TPU_V5E, CPU_HASWELL, VmemMisfitError,
                        choose_blocking, choose_depthwise_blocking,
                        choose_depthwise_wgrad_blocking, choose_dgrad_blocking,
                        choose_pointwise_blocking,
+                       choose_pointwise_dgrad_blocking,
                        choose_pointwise_wgrad_blocking,
                        choose_stream_blocking, choose_stream_dgrad_blocking,
                        choose_stream_wgrad_blocking, choose_wgrad_blocking,
                        depthwise_resident_bytes,
                        depthwise_wgrad_resident_bytes,
+                       pointwise_dgrad_resident_bytes,
                        pointwise_resident_bytes,
                        pointwise_wgrad_resident_bytes,
                        resident_bytes, stream_resident_bytes,
@@ -612,25 +614,34 @@ def probe_impl(key: DispatchKey, impl: Impl,
                 hi=key.padded_hi, wi=key.padded_wi, ci=key.ci, co=key.co,
                 cob=cob, cib=cib, hob=hob, wob=wob,
                 fused_residual=f_res, fused_gap=f_gap, **common)
+        if key.direction == "dgrad" and f_dz:
+            # every input-channel block in one step, dz formed once
+            ciblk = -(-key.ci // cib)
+            return _probe(
+                choose_pointwise_dgrad_blocking,
+                lambda b, kw: pointwise_dgrad_resident_bytes(
+                    b.hob, b.wob, b.cob, b.cib, ciblk,
+                    pol.operand_itemsize, pol.accum_itemsize),
+                ho=key.ho, wo=key.wo, ciblk=ciblk, cob=cob, cib=cib,
+                hob=hob, wob=wob, **common)
         if key.direction == "dgrad":
             # transposed channel matmul: pencils swap roles
             return _probe(
                 choose_pointwise_blocking,
                 lambda b, kw: pointwise_resident_bytes(
                     b.hob, b.wob, b.cob, b.cib,
-                    pol.operand_itemsize, pol.accum_itemsize,
-                    fused_prologue=f_dz),
+                    pol.operand_itemsize, pol.accum_itemsize),
                 hi=key.ho, wi=key.wo, ci=key.co, co=key.ci,
-                cob=cib, cib=cob, hob=hob, wob=wob,
-                fused_prologue=f_dz, **common)
+                cob=cib, cib=cob, hob=hob, wob=wob, **common)
+        # the wgrad reads the dgrad's dz: no prologue, db rides with it
         return _probe(
             choose_pointwise_wgrad_blocking,
             lambda b, kw: pointwise_wgrad_resident_bytes(
                 b.hob, b.wob, b.cob, b.cib,
                 pol.operand_itemsize, pol.accum_itemsize,
-                fused_prologue=f_dz, fused_bias=f_dz),
+                fused_bias=f_dz),
             ho=key.ho, wo=key.wo, cob=cob, cib=cib, hob=hob, wob=wob,
-            fused_prologue=f_dz, fused_bias=f_dz, **common)
+            fused_bias=f_dz, **common)
 
     groups = spec.groups                 # WINDOW (dense) / GROUPED / STREAM
     if key.direction == "fwd":

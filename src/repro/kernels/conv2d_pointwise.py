@@ -18,7 +18,10 @@ Forward grid (the window schedule minus the taps):
 dgrad swaps the pencils (``dy @ w`` contracting Cob — the transposed
 matmul; no cotangent dilation, no halo pad, no mirrored taps), wgrad makes
 (N, Ho/Hob, Wo/Wob) the reduction into a resident ``[Cib, Cob]`` f32 block
-(``x_tileᵀ @ dy_tile`` contracting spatial positions).
+(``x_tileᵀ @ dy_tile`` contracting spatial positions).  Under a fused
+activation the dgrad runs on ``(N, Ho/Hob, Wo/Wob, Co/Cob)`` with every
+input-channel block in each step, so it forms ``dz = g * act'(z)`` once
+per element, and writes ``dz`` back for the wgrad to read.
 
 ``pointwise_conv2d_blocked_pallas`` carries the family's ``jax.custom_vjp``
 with the same precision discipline as the other families.  The entry point
@@ -27,6 +30,7 @@ the dispatcher only routes it where ``ConvSpec.is_pointwise`` holds.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
@@ -38,10 +42,12 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.backend import resolve_interpret, resolve_machine
 from repro.core.blocking import (MachineModel,
                                  choose_pointwise_blocking,
+                                 choose_pointwise_dgrad_blocking,
                                  choose_pointwise_wgrad_blocking)
 from repro.core.direct_conv import apply_activation
 from repro.core.padding import normalize_padding
 from repro.core.precision import F32, Precision, resolve_precision
+from repro.utils.spans import now, record
 from .conv2d_common import (bias_spec, compiler_params, cotangent_prologue,
                             epilogue_flush, first_step, forward_semantics,
                             gap_spec, gap_update, last_step, pencils,
@@ -87,23 +93,14 @@ def _pw_fwd_kernel(x_ref, w_ref, *rest, hob, wob, activation, has_bias,
             gap_update(g_ref, gacc_ref, tile, hw, gap_first, gap_last)
 
 
-def _pw_dgrad_kernel(dy_ref, *rest, hob, wob, has_z, activation):
+def _pw_dgrad_kernel(dy_ref, w_ref, o_ref, acc_ref, *, hob, wob):
     """Transposed channel matmul: contract the Cob lanes of the cotangent
-    against the weight matrix's output axis.  ``has_z`` applies the
-    activation prologue ``dz = g * act'(z)`` to the cotangent tile before
-    the matmul — no halo, so z rides the same plain tile spec."""
-    rest = list(rest)
-    z_ref = rest.pop(0) if has_z else None
-    w_ref, o_ref, acc_ref = rest
-
+    against the weight matrix's output axis."""
     @pl.when(first_step((4,)))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     dy = dy_ref[0, 0].reshape(hob * wob, dy_ref.shape[-1])
-    if z_ref is not None:
-        z = z_ref[0, 0].reshape(hob * wob, dy_ref.shape[-1])
-        dy = cotangent_prologue(dy, z, activation)
     # [Hob*Wob, Cob] x [Cib, Cob] -> [Hob*Wob, Cib]
     acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
         dy, w_ref[0, 0, 0, 0], (((1,), (1,)), ((), ())),
@@ -114,17 +111,43 @@ def _pw_dgrad_kernel(dy_ref, *rest, hob, wob, has_z, activation):
         epilogue_flush(o_ref, acc_ref[...], hob, wob)
 
 
-def _pw_wgrad_kernel(x_ref, dy_ref, *rest, hob, wob, has_z, activation,
-                     with_db):
+def _pw_dgrad_prologue_kernel(dy_ref, z_ref, w_ref, o_ref, dz_ref, acc_ref,
+                              *, hob, wob, activation):
+    """The dgrad with the activation prologue, on the grid ``(n, th, tw,
+    co)``: each step forms ``dz = g * act'(z)`` once for its cotangent
+    tile, writes it back for the wgrad, and contracts it against every
+    input-channel block of the weight into one f32 accumulator per block
+    (``acc[c] += dz @ w[co, c]ᵀ``) — the same per-``co`` accumulation
+    order as the prologue-free kernel, so dx keeps its bits."""
+    ciblk, cob = w_ref.shape[1], dy_ref.shape[-1]
+
+    @pl.when(first_step((3,)))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    dz = cotangent_prologue(dy_ref[0, 0].reshape(hob * wob, cob),
+                            z_ref[0, 0].reshape(hob * wob, cob), activation)
+    dz_ref[0, 0] = dz.reshape(hob, wob, cob)
+    for c in range(ciblk):
+        acc_ref[c] = acc_ref[c] + jax.lax.dot_general(
+            dz, w_ref[0, c, 0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(last_step((3,)))
+    def _flush():
+        for c in range(ciblk):
+            o_ref[0, c] = acc_ref[c].reshape(
+                hob, wob, o_ref.shape[-1]).astype(o_ref.dtype)
+
+
+def _pw_wgrad_kernel(x_ref, dy_ref, *rest, hob, wob, with_db):
     """Weight gradient: contract the spatial positions of the x tile against
     the cotangent tile into a resident [Cib, Cob] block.
 
-    ``has_z`` forms ``dz = g * act'(z)`` on tile load; ``with_db``
-    accumulates ``db = Σ dz`` into a [1, Cob] f32 scratch on the ci == 0
-    pass only (the (n, th, tw) reduction visits every tile exactly once
-    per ci step), flushed once per co block."""
+    ``with_db`` accumulates ``db = Σ dy`` into a [1, Cob] f32 scratch on
+    the ci == 0 pass only (the (n, th, tw) reduction visits every tile
+    exactly once per ci step), flushed once per co block."""
     rest = list(rest)
-    z_ref = rest.pop(0) if has_z else None
     o_ref = rest.pop(0)
     db_ref = rest.pop(0) if with_db else None
     acc_ref = rest.pop(0)
@@ -136,9 +159,6 @@ def _pw_wgrad_kernel(x_ref, dy_ref, *rest, hob, wob, has_z, activation,
 
     x = x_ref[0, 0].reshape(hob * wob, x_ref.shape[-1])
     dy = dy_ref[0, 0].reshape(hob * wob, dy_ref.shape[-1])
-    if z_ref is not None:
-        z = z_ref[0, 0].reshape(hob * wob, dy_ref.shape[-1])
-        dy = cotangent_prologue(dy, z, activation)
 
     if with_db:
         db_first = first_step((2, 3, 4))
@@ -233,49 +253,43 @@ def pointwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
                            machine: Optional[MachineModel] = None,
                            interpret: Optional[bool] = None,
                            z: Optional[jnp.ndarray] = None,
-                           activation: Optional[str] = None) -> jnp.ndarray:
+                           activation: Optional[str] = None):
     """Input gradient of the pointwise conv — the transposed channel matmul.
     No dilation, no halo pad: dx has the input's spatial extents already.
 
-    ``z``/``activation`` fuse the prologue ``dz = g * act'(z)`` on tile
-    load (``z`` is the saved pre-activation, same shape as ``dy``)."""
+    With ``z`` (the saved pre-activation, shape of ``dy``) and
+    ``activation`` the launch forms ``dz = g * act'(z)`` once per element
+    and returns ``(dx, dz)``: ``dz`` in ``dy``'s dtype, written over
+    ``dy``'s buffer where XLA can give it up, for the weight gradient to
+    read.  Each such launch records ``kernels.pw_prologue`` while it is
+    traced (DESIGN.md §14)."""
     machine = resolve_machine(machine)
     interpret = resolve_interpret(interpret)
     n, coblk, ho, wo, cob = dy.shape
     coblk2, ciblk, one, one2, cib, cob2 = w.shape
     assert (coblk, cob) == (coblk2, cob2) and one == one2 == 1, \
         (dy.shape, w.shape)
+    if z is not None:
+        assert z.shape == dy.shape, (z.shape, dy.shape)
+        return _pw_dgrad_prologue(dy, z, w, activation, hob, wob, machine,
+                                  interpret)
 
     # the transposed matmul's pencils swap: cib becomes the lane (output)
     # pencil, cob the contraction depth
     blk = choose_pointwise_blocking(ho, wo, coblk * cob, ciblk * cib,
                                     machine=machine, cob=cib, cib=cob,
                                     hob=hob, wob=wob,
-                                    in_dtype_bytes=dy.dtype.itemsize,
-                                    fused_prologue=z is not None)
+                                    in_dtype_bytes=dy.dtype.itemsize)
     hob, wob = blk.hob, blk.wob
-
-    has_z = z is not None
-    operands = [dy]
-    in_specs = [
-        tile_spec(hob, wob, cob,
-                  lambda b, ci, th, tw, co: (b, co, th, tw)),
-    ]
-    if has_z:
-        assert z.shape == dy.shape, (z.shape, dy.shape)
-        operands.append(z)
-        in_specs.append(tile_spec(hob, wob, cob,
-                                  lambda b, ci, th, tw, co: (b, co, th, tw)))
-    operands.append(w)
-    in_specs.append(weight_spec(1, 1, cib, cob,
-                                lambda b, ci, th, tw, co: (co, ci)))
-
     grid = (n, ciblk, ho // hob, wo // wob, coblk)
     return pl.pallas_call(
-        partial(_pw_dgrad_kernel, hob=hob, wob=wob, has_z=has_z,
-                activation=activation),
+        partial(_pw_dgrad_kernel, hob=hob, wob=wob),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[
+            tile_spec(hob, wob, cob,
+                      lambda b, ci, th, tw, co: (b, co, th, tw)),
+            weight_spec(1, 1, cib, cob, lambda b, ci, th, tw, co: (co, ci)),
+        ],
         out_specs=tile_spec(hob, wob, cib,
                             lambda b, ci, th, tw, co: (b, ci, th, tw)),
         out_shape=jax.ShapeDtypeStruct((n, ciblk, ho, wo, cib), dy.dtype),
@@ -283,26 +297,68 @@ def pointwise_dgrad_pallas(dy: jnp.ndarray, w: jnp.ndarray,
         compiler_params=compiler_params(
             machine, ("parallel",) * 4 + ("arbitrary",)),
         interpret=interpret,
-    )(*operands)
+    )(dy, w)
+
+
+def _pw_dgrad_prologue(dy, z, w, activation, hob, wob, machine, interpret):
+    """The prologue dgrad's launch (see :func:`pointwise_dgrad_pallas`):
+    grid ``(n, th, tw, co)`` with ``co`` the reduction, every input-channel
+    block in each step, the dx block resident across ``co``."""
+    n, coblk, ho, wo, cob = dy.shape
+    _, ciblk, _, _, cib, _ = w.shape
+    blk = choose_pointwise_dgrad_blocking(
+        ho, wo, ciblk, machine=machine, cob=cob, cib=cib, hob=hob, wob=wob,
+        in_dtype_bytes=dy.dtype.itemsize)
+    hob, wob = blk.hob, blk.wob
+    grid = (n, ho // hob, wo // wob, coblk)
+    cot = tile_spec(hob, wob, cob, lambda b, th, tw, co: (b, co, th, tw))
+    # grid steps over cotangent tiles: how often each dz element is formed
+    evaluations = math.prod(grid) // (n * coblk * grid[1] * grid[2])
+    t = now()
+    record("kernels.pw_prologue", t, t, attrs={
+        "direction": "dgrad", "ci_blocks": ciblk, "rows": hob * wob,
+        "evaluations": evaluations})
+    return pl.pallas_call(
+        partial(_pw_dgrad_prologue_kernel, hob=hob, wob=wob,
+                activation=activation),
+        grid=grid,
+        in_specs=[
+            cot, cot,
+            pl.BlockSpec((1, ciblk, 1, 1, cib, cob),
+                         lambda b, th, tw, co: (co, 0, 0, 0, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, ciblk, hob, wob, cib),
+                         lambda b, th, tw, co: (b, 0, th, tw, 0)),
+            cot,
+        ],
+        out_shape=[jax.ShapeDtypeStruct((n, ciblk, ho, wo, cib), dy.dtype),
+                   jax.ShapeDtypeStruct(dy.shape, dy.dtype)],
+        scratch_shapes=[pltpu.VMEM((ciblk, hob * wob, cib), jnp.float32)],
+        # each cotangent tile is read and its dz written in the same step
+        input_output_aliases={0: 1},
+        compiler_params=compiler_params(
+            machine, ("parallel",) * 3 + ("arbitrary",)),
+        interpret=interpret,
+    )(dy, z, w)
 
 
 @partial(jax.jit, static_argnames=("hob", "wob", "machine", "interpret",
-                                   "out_dtype", "activation", "with_db"))
+                                   "out_dtype", "with_db"))
 def pointwise_wgrad_pallas(x: jnp.ndarray, dy: jnp.ndarray,
                            hob: Optional[int] = None,
                            wob: Optional[int] = None,
                            machine: Optional[MachineModel] = None,
                            interpret: Optional[bool] = None,
                            out_dtype=None,
-                           z: Optional[jnp.ndarray] = None,
-                           activation: Optional[str] = None,
                            with_db: bool = False):
     """Weight gradient of the pointwise conv: Σ_tiles x_tileᵀ @ dy_tile into
     the [Co/Cob, Ci/Cib, 1, 1, Cib, Cob] blocked weight layout.
 
-    ``z``/``activation`` fuse ``dz = g * act'(z)`` on tile load;
-    ``with_db`` additionally returns ``(dw, db)`` with ``db = Σ dz``
-    accumulated f32 in-kernel, shape ``[Co/Cob, Cob]``."""
+    ``dy`` is the cotangent of the conv's own output — under a fused
+    activation the ``dz`` the prologue dgrad formed.  ``with_db``
+    additionally returns ``(dw, db)`` with ``db = Σ dy`` accumulated f32
+    in-kernel, shape ``[Co/Cob, Cob]``."""
     machine = resolve_machine(machine)
     interpret = resolve_interpret(interpret)
     n, ciblk, hi, wi, cib = x.shape
@@ -311,23 +367,15 @@ def pointwise_wgrad_pallas(x: jnp.ndarray, dy: jnp.ndarray,
 
     blk = choose_pointwise_wgrad_blocking(
         ho, wo, machine=machine, cob=cob, cib=cib, hob=hob, wob=wob,
-        in_dtype_bytes=x.dtype.itemsize,
-        fused_prologue=z is not None, fused_bias=with_db)
+        in_dtype_bytes=x.dtype.itemsize, fused_bias=with_db)
     hob, wob = blk.hob, blk.wob
 
-    has_z = z is not None
-    operands = [x, dy]
     in_specs = [
         tile_spec(hob, wob, cib,
                   lambda co, ci, b, th, tw: (b, ci, th, tw)),
         tile_spec(hob, wob, cob,
                   lambda co, ci, b, th, tw: (b, co, th, tw)),
     ]
-    if has_z:
-        assert z.shape == dy.shape, (z.shape, dy.shape)
-        operands.append(z)
-        in_specs.append(tile_spec(hob, wob, cob,
-                                  lambda co, ci, b, th, tw: (b, co, th, tw)))
 
     out_specs = weight_spec(1, 1, cib, cob,
                             lambda co, ci, b, th, tw: (co, ci))
@@ -343,8 +391,7 @@ def pointwise_wgrad_pallas(x: jnp.ndarray, dy: jnp.ndarray,
 
     grid = (coblk, ciblk, n, ho // hob, wo // wob)
     return unpencil(pl.pallas_call(
-        partial(_pw_wgrad_kernel, hob=hob, wob=wob, has_z=has_z,
-                activation=activation, with_db=with_db),
+        partial(_pw_wgrad_kernel, hob=hob, wob=wob, with_db=with_db),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -352,7 +399,7 @@ def pointwise_wgrad_pallas(x: jnp.ndarray, dy: jnp.ndarray,
         scratch_shapes=scratch,
         compiler_params=compiler_params(machine, wgrad_semantics(with_db)),
         interpret=interpret,
-    )(*operands), with_db)
+    )(x, dy), with_db)
 
 
 # ---------------------------------------------------------------------------
@@ -398,8 +445,9 @@ def _pwconv_fwd(x, w, bias, residual, activation, hob, wob, machine,
 def _pwconv_bwd(activation, hob, wob, machine, interpret, precision, gap,
                 res, g):
     """No pad/dilate bookkeeping anywhere: the pointwise backward is two
-    more channel matmuls over the same tiles — with the activation
-    prologue (and the bias cotangent) fused into them."""
+    more channel matmuls over the same tiles.  The dgrad forms the
+    activation prologue's ``dz`` once and hands it to the wgrad, which
+    folds the bias cotangent into its flush."""
     xq, wq, bias, z, r_token, x_token, w_token = res
 
     if gap:
@@ -409,20 +457,25 @@ def _pwconv_bwd(activation, hob, wob, machine, interpret, precision, gap,
         g = jnp.broadcast_to(gm, (n, coblk, hi, wi, cob))
     g = g.astype(precision.op_dtype)
     dres = None if r_token is None else g.astype(r_token.dtype)
-    zs = None if z is None else z.astype(g.dtype)
 
-    dx = pointwise_dgrad_pallas(g, wq, machine=machine, interpret=interpret,
-                                z=zs,
-                                activation=activation).astype(x_token.dtype)
+    if z is None:
+        dx = pointwise_dgrad_pallas(g, wq, machine=machine,
+                                    interpret=interpret)
+        dz = g
+    else:
+        dx, dz = pointwise_dgrad_pallas(
+            g, wq, machine=machine, interpret=interpret,
+            z=z.astype(g.dtype), activation=activation)
+    dx = dx.astype(x_token.dtype)
     if bias is not None:
         dw, db32 = pointwise_wgrad_pallas(
-            xq, g, machine=machine, interpret=interpret,
-            out_dtype=jnp.float32, z=zs, activation=activation, with_db=True)
+            xq, dz, machine=machine, interpret=interpret,
+            out_dtype=jnp.float32, with_db=True)
         db = db32.astype(bias.dtype)
     else:
         dw = pointwise_wgrad_pallas(
-            xq, g, machine=machine, interpret=interpret,
-            out_dtype=jnp.float32, z=zs, activation=activation)
+            xq, dz, machine=machine, interpret=interpret,
+            out_dtype=jnp.float32)
         db = None
     dw = dw.astype(w_token.dtype)
     return dx, dw, db, dres

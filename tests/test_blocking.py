@@ -116,3 +116,90 @@ def test_padded_tiles_count_what_mosaic_allocates():
     # a narrow stem pencil costs whole lanes on the chip
     b = choose_blocking(225, 225, 3, 32, 3, 3, 2, machine=TPU_V5E)
     assert b.hob * b.wob <= TPU_V5E.max_tile_rows
+
+
+# ---------------------------------------------------------------------------
+# pointwise backward: the prologue dgrad takes every input-channel block
+# into its grid step; the wgrad reads dz and carries no prologue
+# ---------------------------------------------------------------------------
+
+def _pw_dgrad(hw, ci, co, cb_in, cb_out, machine=TPU_V5E):
+    from repro.core.blocking import (choose_pointwise_dgrad_blocking,
+                                     pointwise_dgrad_resident_bytes)
+    ciblk = ci // cb_in
+    b = choose_pointwise_dgrad_blocking(hw, hw, ciblk, machine=machine,
+                                        cob=cb_out, cib=cb_in,
+                                        precision="bf16")
+    resident = pointwise_dgrad_resident_bytes(b.hob, b.wob, b.cob, b.cib,
+                                              ciblk, 2, 4, machine.tile)
+    assert resident <= machine.vmem_bytes
+    assert ciblk * b.hob * b.wob <= machine.max_tile_rows
+    assert hw % b.hob == 0 and hw % b.wob == 0
+    return b
+
+
+@pytest.mark.parametrize("hw,c,cb", [(56, 96, 96), (28, 192, 96),
+                                     (14, 384, 128), (7, 768, 128)])
+def test_pointwise_dgrad_fits_convnext_pw1(hw, c, cb):
+    """ConvNeXt-T's pw1 (C -> 4C): VMEM never shrinks the step; only the
+    56x56 map's 3136 rows meet the row cap, which the prologue-free
+    dgrad's tile meets too."""
+    from repro.core.blocking import choose_pointwise_blocking
+    b = _pw_dgrad(hw, c, 4 * c, cb, cb)
+    plain = choose_pointwise_blocking(hw, hw, 4 * c, c, cob=cb, cib=cb,
+                                      precision="bf16")
+    assert (b.hob, b.wob) == (plain.hob, plain.wob)
+    assert (b.hob, b.wob) == ((28, 56) if hw == 56 else (hw, hw))
+
+
+@pytest.mark.parametrize("hw,ci,co,cb_in,cb_out", [
+    (112, 32, 64, 32, 64), (56, 64, 128, 64, 128),
+    (7, 512, 1024, 128, 128), (7, 1024, 1024, 128, 128)])
+def test_pointwise_dgrad_fits_mobilenet(hw, ci, co, cb_in, cb_out):
+    """MobileNet's large maps shrink rows to the cap and fit; its
+    1024-channel 7x7 maps keep the whole map with 4 and 8 accumulators."""
+    b = _pw_dgrad(hw, ci, co, cb_in, cb_out)
+    rows = {112: 14 * 112, 56: 28 * 56}.get(hw, hw * hw)
+    assert b.hob * b.wob == rows
+
+
+def test_pointwise_dgrad_accumulators_shrink_rows():
+    """The ``ciblk`` accumulators are what the step holds more of: on a
+    machine where MobileNet's conv13 step fits with one input-channel
+    block but not with eight, the chooser shrinks rows until it fits."""
+    import dataclasses
+    from repro.core.blocking import pointwise_dgrad_resident_bytes
+    one = pointwise_dgrad_resident_bytes(7, 7, 128, 128, 1, 2, 4,
+                                         TPU_V5E.tile)
+    eight = pointwise_dgrad_resident_bytes(7, 7, 128, 128, 8, 2, 4,
+                                           TPU_V5E.tile)
+    assert one < eight
+    tight = dataclasses.replace(TPU_V5E, vmem_bytes=(one + eight) // 2)
+    b = _pw_dgrad(7, 1024, 1024, 128, 128, machine=tight)
+    assert b.hob < 7
+    # the row cap counts every accumulator's rows
+    capped = dataclasses.replace(TPU_V5E, max_tile_rows=8 * 49 - 1)
+    assert _pw_dgrad(7, 1024, 1024, 128, 128, machine=capped).hob < 7
+
+
+@pytest.mark.parametrize("hw,cb_in,cb_out", [(56, 96, 96), (14, 128, 128),
+                                             (7, 128, 128), (112, 32, 64)])
+def test_pointwise_wgrad_holds_no_prologue(hw, cb_in, cb_out):
+    """The wgrad reads the dgrad's dz: its step holds the x and cotangent
+    tiles, the weight-gradient block and accumulator, and with a fused
+    bias three [1, Cob] pencils — no z tile."""
+    from repro.core.blocking import (choose_pointwise_wgrad_blocking,
+                                     pointwise_wgrad_resident_bytes)
+    with pytest.raises(TypeError):
+        pointwise_wgrad_resident_bytes(hw, hw, cb_out, cb_in,
+                                       fused_prologue=True)
+    b = choose_pointwise_wgrad_blocking(hw, hw, cob=cb_out, cib=cb_in,
+                                        precision="bf16", fused_bias=True)
+    assert b.hob * b.wob <= TPU_V5E.max_tile_rows
+    bare = pointwise_wgrad_resident_bytes(b.hob, b.wob, cb_out, cb_in, 2, 4,
+                                          tile=TPU_V5E.tile)
+    with_db = pointwise_wgrad_resident_bytes(b.hob, b.wob, cb_out, cb_in, 2,
+                                             4, fused_bias=True,
+                                             tile=TPU_V5E.tile)
+    assert with_db - bare == 3 * 8 * 128 * 4
+    assert with_db <= TPU_V5E.vmem_bytes

@@ -12,6 +12,7 @@ only one process may load the TPU library, and the suite runs under
 several workers.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,6 +115,47 @@ def test_pointwise_compiles(one_chip, precision):
                     argnums=(0, 1, 2))
     _compile(one_chip, grad, (2, 2, 28, 28, 128), (2, 2, 1, 1, 128, 128),
              (2, 128))
+
+
+def _defs(text):
+    """-> {instruction name: its HLO line}."""
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+        if m:
+            out[m.group(1)] = line
+    return out
+
+
+# the pointwise gradient with a fused activation: ConvNeXt-T's pw1 at
+# 14x14 and 7x7 (exact GELU), MobileNet's conv13 (ReLU)
+@pytest.mark.parametrize("hw,ci,co,activation", [
+    (14, 384, 1536, "gelu_erf"), (7, 768, 3072, "gelu_erf"),
+    (7, 1024, 1024, "relu")])
+def test_pointwise_prologue_grad_compiles(one_chip, hw, ci, co, activation):
+    def fwd(x, w, b):
+        return pointwise_conv2d_blocked_pallas(
+            x, w, b, activation=activation, precision="bf16", **KW)
+
+    # a cotangent computed in the step (as a training step's is), not a
+    # constant XLA could re-materialize, nor a parameter it must keep
+    grad = jax.grad(
+        lambda x, w, b: jnp.sin(fwd(x, w, b).astype(jnp.float32)).sum(),
+        argnums=(0, 1, 2))
+    text = _compile(one_chip, grad, (2, ci // 128, hw, hw, 128),
+                    (co // 128, ci // 128, 1, 1, 128, 128), (co // 128, 128))
+    defs = _defs(text)
+    launches = {n: l for n, l in defs.items() if "tpu_custom_call" in l}
+    for name in ("pointwise_conv2d_blocked_pallas", "pointwise_dgrad_pallas",
+                 "pointwise_wgrad_pallas"):
+        assert sum(name in n for n in launches) == 1, (name, list(launches))
+    (dgrad,) = [l for n, l in launches.items()
+                if "pointwise_dgrad_pallas" in n]
+    # dz is written over the cotangent's buffer, and XLA needed no copy
+    # of the cotangent to give that buffer up
+    assert "output_to_operand_aliasing={{1}: (0, {})}" in dgrad
+    cot = re.search(r"custom-call\(%?([\w.\-]+)", dgrad).group(1)
+    assert " copy(" not in defs[cot], defs[cot]
 
 
 @pytest.mark.parametrize("precision", DTYPES)
